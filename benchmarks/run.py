@@ -9,7 +9,9 @@ table/figure + the roofline tables.  Prints ``name,value,...`` CSV blocks.
   steptime  — optimizer update wall time
   roofline  — per (arch x cell) roofline terms from the dry-run artifacts
 
-Run a subset: ``python -m benchmarks.run fig2 table2``.
+Run a subset: ``python -m benchmarks.run fig2 table2``.  Every section
+runs even after one fails; the exit code is 1 if any failed (or was
+unknown).
 """
 from __future__ import annotations
 
@@ -17,9 +19,10 @@ import sys
 import time
 
 
-def main() -> None:
+def main() -> int:
     sections = sys.argv[1:] or ["table2", "fig2", "fig1", "steptime",
                                 "roofline", "fig3", "ablation"]
+    failed = []
     for name in sections:
         t0 = time.time()
         print(f"\n# === {name} " + "=" * 50, flush=True)
@@ -40,6 +43,7 @@ def main() -> None:
                 from benchmarks.roofline import run
             else:
                 print(f"unknown section {name!r}")
+                failed.append(name)
                 continue
             for row in run():
                 print(row)
@@ -48,7 +52,11 @@ def main() -> None:
             import traceback
             traceback.print_exc()
             print(f"# SECTION FAILED {name}: {e}")
+            failed.append(name)
+    if failed:
+        print(f"# FAILED SECTIONS: {' '.join(failed)}")
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -1,54 +1,21 @@
-"""Version-compat shims for the moving parts of the jax API."""
+"""The one place that adapts to the installed jax (0.9)."""
 from __future__ import annotations
 
-import inspect
+from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
-def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions.
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``.
 
-    jax >= 0.7 exposes ``jax.shard_map(check_vma=...)``; 0.6 promoted it to
-    the top level but still spells the kwarg ``check_rep``; older releases
-    only have ``jax.experimental.shard_map.shard_map`` (also ``check_rep``).
-    Dispatch on the actual signature, not mere presence of the attribute.
-    """
-    if hasattr(jax, "shard_map"):
-        params = inspect.signature(jax.shard_map).parameters
-        kw = {"check_vma" if "check_vma" in params else "check_rep":
-              check_vma}
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    jax 0.9 defaults new meshes to Explicit axes, under which
+    ``with_sharding_constraint`` rejects the repo's PartitionSpecs and
+    ``shard_map`` wants a mesh context; the sharding rules here are
+    written for the compiler-propagated (Auto) model.  ``devices`` picks
+    the devices explicitly (e.g. a described topology's)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
-
-def pallas_load(ref, idx: tuple):
-    """``pl.load`` with integer indexers across jax versions.
-
-    On jax 0.4.3x the interpret-mode state-discharge rule for ``load_p``
-    assumes every non-``Slice`` indexer is an array (it probes ``.shape``),
-    so a plain Python ``int`` in the index tuple raises
-    ``AttributeError: 'int' object has no attribute 'shape'`` — but only
-    when the kernel is *interpreted* (CPU tests), not when it is compiled
-    for TPU.  Normalising each int ``i`` to the size-1 slice
-    ``pl.dslice(i, 1)`` and squeezing the resulting unit axes afterwards is
-    bit-identical on every version and lowers to the same DMA on TPU, so we
-    do it unconditionally rather than sniffing the broken rule.
-    """
-    from jax.experimental import pallas as pl
-
-    squeeze_axes = []
-    norm = []
-    for ax, s in enumerate(idx):
-        if isinstance(s, int):
-            norm.append(pl.dslice(s, 1))
-            squeeze_axes.append(ax)
-        else:
-            norm.append(s)
-    out = pl.load(ref, tuple(norm))
-    if squeeze_axes:
-        out = out.squeeze(axis=tuple(squeeze_axes))
-    return out

@@ -21,6 +21,8 @@ from typing import Optional
 
 import jax
 
+from repro.compat import make_mesh
+
 
 @dataclasses.dataclass(frozen=True)
 class MeshPlan:
@@ -66,7 +68,7 @@ def plan_remesh(available_devices: int, target_model: int = 16,
 
 
 def build_mesh(plan: MeshPlan):
-    return jax.make_mesh(plan.shape(), plan.axes())
+    return make_mesh(plan.shape(), plan.axes())
 
 
 def elastic_restore(ckpt_manager, like, make_shardings, *,

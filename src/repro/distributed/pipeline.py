@@ -24,9 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
-
 
 def pipeline_apply(stage_fn: Callable, stage_params, x_micro,
                    mesh: Mesh, axis: str = "stage"):
@@ -72,7 +69,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro,
         # psum broadcasts it (small boundary tensor, one hop in practice)
         return jax.lax.psum(outputs, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

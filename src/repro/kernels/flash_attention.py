@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.compat import pallas_load
-
 NEG_INF = -1e30
 
 
@@ -49,10 +47,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, sk: int,
 
     def body(ki, carry):
         m, l, acc = carry
-        k = pallas_load(k_ref, (0, pl.dslice(ki * bk, bk), slice(None))
-                        ).astype(jnp.float32)         # (bk, dh)
-        v = pallas_load(v_ref, (0, pl.dslice(ki * bk, bk), slice(None))
-                        ).astype(jnp.float32)
+        k = k_ref[0, pl.ds(ki * bk, bk), :].astype(jnp.float32)  # (bk, dh)
+        v = v_ref[0, pl.ds(ki * bk, bk), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if causal:

@@ -55,8 +55,14 @@ VMEM tiling matches lowrank_update.py: blocks (bm, r) of Q, (bn, r) of U,
 (bm, bn) of G / m1 with r padded to the 128-lane quantum by ops.py;
 bm = bn = 256 keeps the footprint ~2 MiB, well inside the ~16 MiB budget
 (and equals core/quantized.py's BLOCK_ROWS, so a quantized tile needs
-exactly one scale/zero row).  Scalars ride in a single small ANY-space
-vector, indexed inside the body.
+exactly one scale/zero row).
+
+TPU placement rules the specs below follow (interpret mode checks none of
+them): scalar operands are one (1, k) row in SMEM, and every per-tile
+partial is a (1, 1) SMEM block of a (gm, gn, 1, 1) output — a block's
+last two dims must be (8, 128)-aligned or span the array, and only SMEM
+holds a scalar store.  The quantized scale/zero rows ride as (gm, 1, r)
+for the same reason.
 """
 from __future__ import annotations
 
@@ -65,6 +71,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# a (1, 1) SMEM block of a (gm, gn, 1, 1) per-tile partial output
+TILE_SPEC = pl.BlockSpec((None, None, 1, 1), lambda i, j: (i, j, 0, 0),
+                          memory_space=pltpu.SMEM)
+SCALARS_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _deq_tile(q8_ref, scale_ref, zero_ref, base_row: jnp.ndarray,
@@ -102,8 +114,8 @@ def _make_precond_kernel(guided: bool, with_fold: bool, quantized: bool,
                                                                    None)
         fold_ref = next(it) if with_fold else None
 
-        b2 = s_ref[0]
-        eps = s_ref[1]
+        b2 = s_ref[0, 0]
+        eps = s_ref[0, 1]
         low = jax.lax.dot_general(q, u, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
         v = b2 * jnp.maximum(low, 0.0) + (1.0 - b2) * g * g
@@ -150,18 +162,20 @@ def fused_precond_pallas(q, u, g: jnp.ndarray, m1, b2, eps,
     n = (u[0] if quantized else u).shape[0]
     gm, gn = m // bm, n // bn
     scalars = jnp.stack([jnp.asarray(b2, jnp.float32),
-                         jnp.asarray(eps, jnp.float32)])
+                         jnp.asarray(eps, jnp.float32)]).reshape(1, 2)
 
     inputs, in_specs = [], []
     if quantized:
-        inputs += [q[0], q[1], q[2], u[0], u[1], u[2]]
+        rows3 = lambda a: a.reshape(a.shape[0], 1, a.shape[1])
+        inputs += [q[0], rows3(q[1]), rows3(q[2]),
+                   u[0], rows3(u[1]), rows3(u[2])]
         in_specs += [
             pl.BlockSpec((bm, r), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, r), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, r), lambda i, j: (i, 0)),
+            pl.BlockSpec((None, 1, r), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, 1, r), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((bn, r), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, r), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, r), lambda i, j: (j, 0)),
+            pl.BlockSpec((None, 1, r), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((None, 1, r), lambda i, j: (j, 0, 0)),
         ]
     else:
         inputs += [q, u]
@@ -175,15 +189,14 @@ def fused_precond_pallas(q, u, g: jnp.ndarray, m1, b2, eps,
         inputs.append(m1)
         in_specs.append(pl.BlockSpec((bm, bn), lambda i, j: (i, j)))
     inputs.append(scalars)
-    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+    in_specs.append(SCALARS_SPEC)
 
-    tile = jax.ShapeDtypeStruct((gm, gn), jnp.float32)
-    tile_spec = pl.BlockSpec((1, 1), lambda i, j: (i, j))
+    tile = jax.ShapeDtypeStruct((gm, gn, 1, 1), jnp.float32)
     out_specs = [pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-                 tile_spec, tile_spec]
+                 TILE_SPEC, TILE_SPEC]
     out_shape = [jax.ShapeDtypeStruct((m, n), jnp.float32), tile, tile]
     if guided:
-        out_specs += [tile_spec, tile_spec]
+        out_specs += [TILE_SPEC, TILE_SPEC]
         out_shape += [tile, tile]
     if with_fold:
         out_specs.append(pl.BlockSpec((1, bn, r), lambda i, j: (i, j, 0)))
@@ -207,15 +220,16 @@ def fused_precond_pallas(q, u, g: jnp.ndarray, m1, b2, eps,
 
 
 def _apply_kernel(u_ref, m1_ref, s_ref, out_ref, m1_new_ref):
-    # s_ref: (5,) = [denom, b1, 1 - b1, out_scale, store_scale].  (1 - b1)
-    # is precomputed by the wrapper in python-f64-then-round — the same
-    # coefficient the jnp paths use — rather than re-derived in f32 here.
+    # s_ref: (1, 5) = [denom, b1, 1 - b1, out_scale, store_scale].
+    # (1 - b1) is precomputed by the wrapper in python-f64-then-round —
+    # the same coefficient the jnp paths use — rather than re-derived in
+    # f32 here.
     u = u_ref[...].astype(jnp.float32)
     m1 = m1_ref[...].astype(jnp.float32)
-    u_c = u / s_ref[0]
-    acc = s_ref[1] * m1 + s_ref[2] * u_c
-    out_ref[...] = acc * s_ref[3]
-    m1_new_ref[...] = acc * s_ref[4]
+    u_c = u / s_ref[0, 0]
+    acc = s_ref[0, 1] * m1 + s_ref[0, 2] * u_c
+    out_ref[...] = acc * s_ref[0, 3]
+    m1_new_ref[...] = acc * s_ref[0, 4]
 
 
 def _apply_shared_kernel(u_ref, m1_ref, s_ref, m1_new_ref):
@@ -224,9 +238,9 @@ def _apply_shared_kernel(u_ref, m1_ref, s_ref, m1_new_ref):
     # in the unfused path — write it once and let the caller alias.
     u = u_ref[...].astype(jnp.float32)
     m1 = m1_ref[...].astype(jnp.float32)
-    u_c = u / s_ref[0]
-    acc = s_ref[1] * m1 + s_ref[2] * u_c
-    m1_new_ref[...] = acc * s_ref[4]
+    u_c = u / s_ref[0, 0]
+    acc = s_ref[0, 1] * m1 + s_ref[0, 2] * u_c
+    m1_new_ref[...] = acc * s_ref[0, 4]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
@@ -245,7 +259,7 @@ def fused_apply_shared_pallas(u_hat: jnp.ndarray, m1: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec(memory_space=pl.ANY),       # scalars (5,)
+            SCALARS_SPEC,                           # (1, 5)
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
@@ -259,7 +273,7 @@ def fused_apply_pallas(u_hat: jnp.ndarray, m1: jnp.ndarray,
                        scalars: jnp.ndarray,
                        bm: int = 256, bn: int = 256,
                        interpret: bool = False):
-    """u_hat/m1: (m, n) f32, scalars: (5,) f32 = [denom, b1, 1 - b1,
+    """u_hat/m1: (m, n) f32, scalars: (1, 5) f32 = [denom, b1, 1 - b1,
     out_scale, store_scale].  m % bm == 0, n % bn == 0 (ops.py pads).  ``m1`` is
     aliased to the ``m1_new`` output (updated in place — the EMA buffer
     never exists twice in HBM).  Returns (m_out, m1_new), both (m, n) f32.
@@ -272,7 +286,7 @@ def fused_apply_pallas(u_hat: jnp.ndarray, m1: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec(memory_space=pl.ANY),       # scalars (4,)
+            SCALARS_SPEC,                           # (1, 5)
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),

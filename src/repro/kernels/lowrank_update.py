@@ -18,6 +18,8 @@ the (bm, r) x (r, bn) product hits the MXU with r padded to a multiple of
 128 by the wrapper in ops.py.  Default bm = bn = 256: VMEM footprint
 ~ 2*256*r_max*4 + 2*256*256*4 bytes ~= 1.5 MiB at r = 256 — comfortably
 inside the ~16 MiB VMEM budget, leaving room for double buffering.
+Scalars and the per-tile ||V||^2 partials sit in SMEM, laid out as in
+fused_update.py.
 """
 from __future__ import annotations
 
@@ -27,13 +29,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.fused_update import SCALARS_SPEC, TILE_SPEC
 
-def _kernel(q_ref, u_ref, g_ref, b2_ref, eps_ref, out_ref, vfro_ref):
+
+def _kernel(q_ref, u_ref, g_ref, s_ref, out_ref, vfro_ref):
     q = q_ref[...].astype(jnp.float32)          # (bm, r)
     u = u_ref[...].astype(jnp.float32)          # (bn, r)
     g = g_ref[...].astype(jnp.float32)          # (bm, bn)
-    b2 = b2_ref[0]
-    eps = eps_ref[0]
+    b2 = s_ref[0, 0]
+    eps = s_ref[0, 1]
     low = jax.lax.dot_general(q, u, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (bm, bn)
     v = b2 * jnp.maximum(low, 0.0) + (1.0 - b2) * g * g
@@ -59,18 +63,17 @@ def lowrank_update_pallas(q: jnp.ndarray, u: jnp.ndarray, g: jnp.ndarray,
             pl.BlockSpec((bm, r), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, r), lambda i, j: (j, 0)),
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec(memory_space=pl.ANY),   # b2 scalar (1,)
-            pl.BlockSpec(memory_space=pl.ANY),   # eps scalar (1,)
+            SCALARS_SPEC,                       # (1, 2) = [b2, eps]
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            TILE_SPEC,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, n), jnp.float32),
-            jax.ShapeDtypeStruct((gm, gn), jnp.float32),
+            jax.ShapeDtypeStruct((gm, gn, 1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q, u, g, jnp.reshape(b2.astype(jnp.float32), (1,)),
-      jnp.reshape(eps.astype(jnp.float32), (1,)))
+    )(q, u, g, jnp.stack([b2.astype(jnp.float32),
+                          eps.astype(jnp.float32)]).reshape(1, 2))
     return out, jnp.sum(vfro)

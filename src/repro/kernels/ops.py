@@ -11,6 +11,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
@@ -293,7 +294,7 @@ def fused_apply(u_hat: jnp.ndarray, m1: jnp.ndarray | None,
                              jnp.asarray(b1, jnp.float32),
                              jnp.asarray(1.0 - b1, jnp.float32),
                              os_.astype(jnp.float32),
-                             ss.astype(jnp.float32)])
+                             ss.astype(jnp.float32)]).reshape(1, 5)
         if shared_out:
             m1n = fused_apply_shared_pallas(up, mp, scalars, bm=bm, bn=bn,
                                             interpret=interp)
@@ -390,8 +391,24 @@ def sketch_update(table: jnp.ndarray, g: jnp.ndarray, idx: jnp.ndarray,
     tab = _pad_to(_pad_to(table.astype(jnp.float32), 128, 1), bd, 2)
     gp = _pad_to(_pad_to(g, br, 0), bd, 1)
     ip = _pad_to(idx, br, 1)
-    new, vhat = sketch_update_pallas(tab, gp, ip, jnp.asarray(b2),
-                                     br=br, bd=bd, interpret=interp)
+
+    def call(tab, gp, ip):
+        return sketch_update_pallas(tab, gp, ip, jnp.asarray(b2), br=br,
+                                    bd=bd, interpret=interp)
+
+    # XLA cannot partition a Mosaic kernel.  Under a mesh every device
+    # runs it on its own slice of the inner axis — each column is an
+    # independent sketch, and FSDP already shards embedding tables there —
+    # or, when the slices would not hold whole d-blocks, on all of it.
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty and mesh.size > 1:
+        ax = mesh.axis_names if (tab.shape[2] // bd) % mesh.size == 0 \
+            else None
+        call = jax.shard_map(
+            call, mesh=mesh, check_vma=False,
+            in_specs=(P(None, None, ax), P(None, ax), P()),
+            out_specs=(P(None, None, ax), P(None, ax)))
+    new, vhat = call(tab, gp, ip)
     return new[:, :width, :d], vhat[:rows, :d]
 
 

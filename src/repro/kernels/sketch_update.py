@@ -24,11 +24,17 @@ the min-over-depth gather (TPU grids run sequentially, so phase 0 finishes
 before phase 1 starts).  The vhat block is fully overwritten in phase 1,
 so its phase-0 placeholder write never matters.
 
-VMEM: 2 * depth*w*bd (table in/out) + br*bd (G) + br*w (one-hot) f32.  At
-the default depth = 4, w = 2048, bd = 128, br = 256 that is ~10.3 MiB —
-inside the ~16 MiB budget; ops.py shrinks bd first when the table is
-wider.  Padding contract (ops.py): padded rows carry zero gradient and
-bucket 0, so they scatter no mass; padded buckets are never queried.
+VMEM (f32, every blocked operand double-buffered by the pipeline): the
+table in and out blocks 2 * 2 * depth*w*bd, G and vhat 2 * 2 * br*bd, the
+index block 2 * depth*br, plus the (br, w) one-hot and (w, bd) scatter
+product live in the body.  At the launcher's default depth = 4,
+w = 2048, bd = 128, br = 256 that is ~19.6 MiB — over the TPU's 16 MiB
+default scoped-VMEM limit, so the call sets ``vmem_limit_bytes`` from
+:func:`vmem_bytes` with headroom; ops.py shrinks bd first when the table
+is wider.  b2 is a (1, 1) SMEM operand (a scalar load must come from
+SMEM or VMEM).  Padding contract (ops.py): padded rows carry zero
+gradient and bucket 0, so they scatter no mass; padded buckets are never
+queried.
 """
 from __future__ import annotations
 
@@ -37,6 +43,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_MIB = 1 << 20
+
+
+def vmem_bytes(depth: int, w: int, bd: int, br: int) -> int:
+    """VMEM the kernel needs at one block plan (see module docstring)."""
+    blocks = 2 * (2 * depth * w * bd + 2 * br * bd + depth * br)
+    body = br * w + w * bd + br * bd
+    return 4 * (blocks + body)
 
 
 def _kernel(idx_ref, g_ref, table_ref, b2_ref, new_ref, vhat_ref):
@@ -44,7 +60,7 @@ def _kernel(idx_ref, g_ref, table_ref, b2_ref, new_ref, vhat_ref):
     i = pl.program_id(2)
     depth, w = table_ref.shape[0], table_ref.shape[1]
     br = g_ref.shape[0]
-    b2 = b2_ref[0]
+    b2 = b2_ref[0, 0]
     idx = idx_ref[...]                                       # (depth, br)
     iota_w = jax.lax.broadcasted_iota(jnp.int32, (br, w), 1)
 
@@ -95,7 +111,7 @@ def sketch_update_pallas(table: jnp.ndarray, g: jnp.ndarray,
             pl.BlockSpec((depth, br), lambda dd, p, i: (0, i)),
             pl.BlockSpec((br, bd), lambda dd, p, i: (i, dd)),
             pl.BlockSpec((depth, w, bd), lambda dd, p, i: (0, 0, dd)),
-            pl.BlockSpec(memory_space=pl.ANY),   # b2 scalar (1,)
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # b2 (1, 1)
         ],
         out_specs=[
             pl.BlockSpec((depth, w, bd), lambda dd, p, i: (0, 0, dd)),
@@ -105,6 +121,8 @@ def sketch_update_pallas(table: jnp.ndarray, g: jnp.ndarray,
             jax.ShapeDtypeStruct((depth, w, d), jnp.float32),
             jax.ShapeDtypeStruct((rows, d), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_bytes(depth, w, bd, br) + 8 * _MIB),
         interpret=interpret,
-    )(idx, g, table, jnp.reshape(b2.astype(jnp.float32), (1,)))
+    )(idx, g, table, jnp.reshape(b2.astype(jnp.float32), (1, 1)))
     return new, vhat

@@ -7,7 +7,7 @@ init, and tests/benches must keep seeing 1 device.
 """
 from __future__ import annotations
 
-import jax
+from repro.compat import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,9 +16,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     DCN data-parallel dimension (only gradient all-reduces cross it)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for CI dry-run tests (requires host-device override)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)

@@ -18,6 +18,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.models import build_model
 from repro.serve import (ContinuousConfig, ContinuousEngine, Engine,
@@ -67,6 +68,7 @@ def main(argv=None):
                          "metrics.prom dump at exit)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))

@@ -3,10 +3,13 @@
     PYTHONPATH=src python -m repro.launch.train --arch gpt2-117m --smoke \
         --steps 200 --optimizer adapprox --ckpt-dir /tmp/ckpt
 
-``--smoke`` trains the reduced config on CPU; without it the full config is
-built (requires real accelerators + the production mesh).  All the
-fault-tolerance machinery (atomic async checkpoints, preemption flush,
-restart-resume, straggler monitor) is active either way.
+``--smoke`` trains the reduced config (CPU-sized); without it the full
+config is built at its published widths.  GPT-2 117M/345M fit one TPU
+v5e chip with no mesh (``chip_smoke.py`` runs the 345M step there);
+larger configs need ``--mesh``.  All the fault-tolerance machinery
+(atomic async checkpoints, preemption flush, restart-resume, straggler
+monitor) is active either way.  JAX's persistent compilation cache is on
+(``repro.compile_cache``).
 
 Sharded runs: ``--mesh 4,2`` builds a (data=4, model=2) device mesh (three
 numbers add a leading DCN ``pod`` axis, one number is pure data
@@ -56,6 +59,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import CheckpointConfig
+from repro.compat import make_mesh
+from repro.compile_cache import enable_compile_cache
 from repro.config import (OptimizerConfig, TelemetryConfig,
                           default_mixed_groups)
 from repro.configs import get_config, get_smoke_config
@@ -129,7 +134,7 @@ def parse_mesh(spec: str):
             f"--mesh {spec} needs {need} devices but only {n_dev} are "
             f"visible; set REPRO_TRAIN_DEVICES={need} for virtual CPU "
             f"devices")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def main(argv=None):
@@ -218,6 +223,7 @@ def main(argv=None):
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(levelname)s %(message)s")
+    log.info("compilation cache: %s", enable_compile_cache())
     mixed = (args.optimizer == "adapprox" if args.mixed_groups is None
              else args.mixed_groups)
     cfg = (get_smoke_config(args.arch, max_seq_len=args.seq)

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models import layers as L
 
 
@@ -180,7 +179,7 @@ def moe_apply_sharded(cfg, p, x, mesh, dp_axes: tuple = ("data",),
         aux = jax.lax.psum(aux, model_axis) / jax.lax.psum(1, model_axis)
         return y.reshape(b, s, d), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axes, None, None), P(None, None),
                   wspec, wspec, P(model_axis, None,
@@ -224,7 +223,7 @@ def moe_apply_ep_tp(cfg, p, x, mesh, model_axis: str = "model",
         aux = jax.lax.psum(aux, both) / n
         return y.reshape(b, s, d), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None, None), P(None, None),
                   wspec_up, wspec_up, wspec_dn),

@@ -155,10 +155,18 @@ def train(model, opt: GradientTransformation, data_cfg: DataConfig,
         # any instant — donation would leave it pointing at freed buffers —
         # so the flush path trades the alias away.
         donate = () if install_signal_handler else (0,)
-        step_fn = jax.jit(step_fn,
-                          in_shardings=(state_shardings, batch_shardings),
-                          out_shardings=(state_shardings, None),
-                          donate_argnums=donate)
+        sharded_step = jax.jit(step_fn,
+                               in_shardings=(state_shardings,
+                                             batch_shardings),
+                               out_shardings=(state_shardings, None),
+                               donate_argnums=donate)
+        mesh = jax.tree.leaves(state_shardings)[0].mesh
+
+        def step_fn(state, batch):
+            # traced under the mesh: kernels XLA cannot partition wrap
+            # themselves in shard_map over it (kernels/ops.py)
+            with jax.set_mesh(mesh):
+                return sharded_step(state, batch)
     else:
         step_fn = jax.jit(step_fn)
 

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.compat import make_mesh
 from repro.config import ModelConfig, MoESpec
 from repro.models import moe as MOE
 
@@ -65,7 +66,7 @@ def test_sharded_equals_local_on_single_device_mesh():
     cfg = cfg_with("sort", cap=64.0)
     p = MOE.moe_init(jax.random.PRNGKey(0), cfg, 32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 32))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     y_l, aux_l = MOE.moe_apply_local(cfg, p, x)
     y_s, aux_s = MOE.moe_apply_sharded(cfg, p, x, mesh, dp_axes=("data",),
                                        gather_axes=())
@@ -89,7 +90,7 @@ def test_ep_tp_equals_local_on_single_device_mesh():
     cfg = cfg_with("sort", cap=64.0)
     p = MOE.moe_init(jax.random.PRNGKey(0), cfg, 32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 1, 32))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     y_l, aux_l = MOE.moe_apply_local(cfg, p, x)
     y_s, aux_s = MOE.moe_apply_ep_tp(cfg, p, x, mesh)
     np.testing.assert_allclose(np.asarray(y_s), np.asarray(y_l),

@@ -24,7 +24,8 @@ cfg = ModelConfig(arch="t", family="moe", n_layers=1, d_model=32, n_heads=4,
 p = MOE.moe_init(jax.random.PRNGKey(0), cfg, 32)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32))
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.compat import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 y_ref, aux_ref = MOE.moe_apply_local(cfg, p, x)
 
 # EP over model + FSDP gather over data (train layout)

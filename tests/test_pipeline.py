@@ -18,7 +18,8 @@ from repro.distributed.pipeline import (pipeline_apply, split_stages,
                                         stage_fn_from_layers)
 
 L, D, M, MB = 8, 16, 6, 4
-mesh = jax.make_mesh((4,), ("stage",))
+from repro.compat import make_mesh
+mesh = make_mesh((4,), ("stage",))
 
 key = jax.random.PRNGKey(0)
 w = jax.random.normal(key, (L, D, D)) * (1.0 / jnp.sqrt(D))

@@ -41,6 +41,7 @@ from repro.data import DataConfig
 from repro.train import LoopConfig, train
 from repro.distributed import sharding as SH
 from repro.checkpoint import CheckpointConfig, CheckpointManager
+from repro.compat import make_mesh
 
 VOCAB, SEQ, BATCH = 128, 32, 8
 
@@ -62,7 +63,7 @@ def setup(mesh_spec):
     mesh = None
     if mesh_spec:
         axes = {1: ("data",), 2: ("data", "model")}[len(mesh_spec)]
-        mesh = jax.make_mesh(mesh_spec, axes)
+        mesh = make_mesh(mesh_spec, axes)
     model = build_model(cfg, mesh)
     opt = make_opt()
     ssh = bsh = None

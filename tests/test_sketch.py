@@ -68,6 +68,55 @@ def test_sketch_update_matches_ref(force_pallas, rows, width, depth, inner,
                                rtol=2e-4, atol=2e-4)
 
 
+SHARDED_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.compat import make_mesh
+from repro.core.sketch import _leaf_seeds, bucket_indices
+from repro.kernels import ops, ref
+rows, width, depth, inner = 300, 256, 2, 1024   # 4 d-blocks of 256
+key = jax.random.PRNGKey(0)
+table = jnp.abs(jax.random.normal(key, (depth, width, inner)))
+g = jax.random.normal(jax.random.fold_in(key, 1), (rows, inner))
+idx = jnp.asarray(bucket_indices(rows, width, _leaf_seeds(0, 0, depth)))
+mesh = make_mesh((4,), ("data",))
+put = lambda x, *spec: jax.device_put(x, NamedSharding(mesh, P(*spec)))
+ops.set_mode("pallas")
+fn = jax.jit(lambda t, g, i: ops.sketch_update(t, g, i, 0.999))
+with jax.set_mesh(mesh):
+    new_k, q_k = fn(put(table, None, None, "data"), put(g, None, "data"),
+                    put(idx))
+    text = fn.lower(put(table, None, None, "data"), put(g, None, "data"),
+                    put(idx)).as_text()
+assert "shard_map" in text or "sdy.manual_computation" in text, text[:2000]
+new_r, q_r = ref.sketch_update(table, g, idx, 0.999)
+np.testing.assert_allclose(np.asarray(new_k), np.asarray(new_r),
+                           rtol=2e-4, atol=2e-4)
+np.testing.assert_allclose(np.asarray(q_k), np.asarray(q_r),
+                           rtol=2e-4, atol=2e-4)
+assert new_k.sharding.spec == P(None, None, "data"), new_k.sharding
+print("SHARDED_SKETCH_OK")
+"""
+
+
+def test_sketch_update_under_mesh_matches_ref():
+    """Under a 4-device mesh the kernel runs per device on its slice of
+    the inner axis (a Mosaic kernel cannot be partitioned by XLA) and
+    still matches the oracle (subprocess: needs its own device count)."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    repo = Path(__file__).resolve().parent.parent
+    env = dict(os.environ, PYTHONPATH=str(repo / "src"),
+               JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", SHARDED_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert "SHARDED_SKETCH_OK" in out.stdout, out.stderr[-3000:]
+
+
 def test_sketch_update_oracle_is_ema_scatter():
     """Hand-check the oracle on a collision: two rows hashed to the same
     bucket accumulate, the query returns the shared bucket."""
