@@ -59,6 +59,7 @@ def scale_by_adam(b1: float = 0.9, b2: float = 0.999,
                           m=jax.tree.map(z, params),
                           v=jax.tree.map(z, params))
 
+    @jax.named_scope("adamw")
     def update(grads, state: AdamWState, params):
         del params
         step = state.step + 1

@@ -355,11 +355,13 @@ def _factored_update_2d(g, q, u, k, xi_prev, m1, key, step,
         # above) and discarded on refresh steps — O(gm n r) partial words,
         # cheap next to the 3 m n the fold pass used to cost.
         with_fold = dynamic or cfg.refresh_every > 1
-        (u_hat_raw, vfro, usq, m1dot, m1sq,
-         yfold) = _kernel_ops().fused_precond(
-            q, u, g32, cfg.b2, cfg.eps, m1=m1 if need_guid else None,
-            with_vfro=cfg.implicit, with_fold=with_fold)
+        with jax.named_scope("precondition"):
+            (u_hat_raw, vfro, usq, m1dot, m1sq,
+             yfold) = _kernel_ops().fused_precond(
+                q, u, g32, cfg.b2, cfg.eps, m1=m1 if need_guid else None,
+                with_vfro=cfg.implicit, with_fold=with_fold)
 
+    @jax.named_scope("srsi")
     def _run_srsi(n_it: int, u0, use_warm):
         op = (v_op if v_op is not None
               else S.make_implicit_v(*q32u32, g32, cfg.b2))
@@ -455,56 +457,59 @@ def _factored_update_2d(g, q, u, k, xi_prev, m1, key, step,
     else:
         q_new, u_new, k_new, xi = _refresh()
 
-    # --- elementwise tail, fused: host-combine the pass-1 reductions into
-    # the clip / guidance scalars, then one read-modify-write (pass 2)
-    # applies clip + first-moment EMA + guidance together.
-    if cfg.fused_update:
-        denom, out_scale, store_scale = _fused_scalars(
-            usq, m1dot, m1sq, g32.size, cfg, need_guid)
-        clip_active = (denom > 1.0).astype(jnp.float32)
+    with jax.named_scope("precondition"):
+        # --- elementwise tail, fused: host-combine the pass-1 reductions
+        # into the clip / guidance scalars, then one read-modify-write
+        # (pass 2) applies clip + first-moment EMA + guidance together.
+        if cfg.fused_update:
+            denom, out_scale, store_scale = _fused_scalars(
+                usq, m1dot, m1sq, g32.size, cfg, need_guid)
+            clip_active = (denom > 1.0).astype(jnp.float32)
+            if cfg.b1 > 0:
+                # guidance "off"/"stored": out_scale == store_scale, so the
+                # step direction IS the new first moment (same as unfused) —
+                # the shared-output kernel writes it once.
+                m_out, m1_new = _kernel_ops().fused_apply(
+                    u_hat_raw, m1, denom, cfg.b1, out_scale, store_scale,
+                    shared_out=cfg.guidance != "update")
+            else:
+                m_out, m1_new = _kernel_ops().fused_apply(
+                    u_hat_raw, None, denom, cfg.b1, out_scale, store_scale)
+            return m_out, q_new, u_new, k_new, xi, m1_new, clip_active
+
+        # --- elementwise update from V_t (prev factors + fresh G^2),
+        # unfused
+        if cfg.use_kernels:
+            u_hat = _kernel_ops().lowrank_update(q, u, g32, cfg.b2, cfg.eps)
+        else:
+            u_hat = g32 / (jnp.sqrt(vmat) + cfg.eps)
+
+        clip_denom = jnp.maximum(1.0, _rms(u_hat) / cfg.clip_d)
+        clip_active = (clip_denom > 1.0).astype(jnp.float32)
+        u_hat = u_hat / clip_denom
+
+        # --- first moment over updates + optional cosine guidance
         if cfg.b1 > 0:
-            # guidance "off"/"stored": out_scale == store_scale, so the
-            # step direction IS the new first moment (same as unfused) —
-            # the shared-output kernel writes it once.
-            m_out, m1_new = _kernel_ops().fused_apply(
-                u_hat_raw, m1, denom, cfg.b1, out_scale, store_scale,
-                shared_out=cfg.guidance != "update")
-        else:
-            m_out, m1_new = _kernel_ops().fused_apply(
-                u_hat_raw, None, denom, cfg.b1, out_scale, store_scale)
-        return m_out, q_new, u_new, k_new, xi, m1_new, clip_active
-
-    # --- elementwise update from V_t (prev factors + fresh G^2), unfused
-    if cfg.use_kernels:
-        u_hat = _kernel_ops().lowrank_update(q, u, g32, cfg.b2, cfg.eps)
-    else:
-        u_hat = g32 / (jnp.sqrt(vmat) + cfg.eps)
-
-    clip_denom = jnp.maximum(1.0, _rms(u_hat) / cfg.clip_d)
-    clip_active = (clip_denom > 1.0).astype(jnp.float32)
-    u_hat = u_hat / clip_denom
-
-    # --- first moment over updates + optional cosine guidance
-    if cfg.b1 > 0:
-        m1_acc = cfg.b1 * m1 + (1.0 - cfg.b1) * u_hat
-        if cfg.guidance != "off":
-            num = jnp.sum(u_hat * m1_acc)
-            den = jnp.sqrt(jnp.sum(u_hat**2)) * jnp.sqrt(jnp.sum(m1_acc**2))
-            theta = num / (den + 1e-30)
-            scale = jnp.clip(1.0 / (1.0 - theta + cfg.eps), 0.0,
-                             cfg.guidance_max_scale)
-            if cfg.guidance == "stored":
-                m1_acc = m1_acc * scale      # Eq. (18) literally
+            m1_acc = cfg.b1 * m1 + (1.0 - cfg.b1) * u_hat
+            if cfg.guidance != "off":
+                num = jnp.sum(u_hat * m1_acc)
+                den = (jnp.sqrt(jnp.sum(u_hat**2))
+                       * jnp.sqrt(jnp.sum(m1_acc**2)))
+                theta = num / (den + 1e-30)
+                scale = jnp.clip(1.0 / (1.0 - theta + cfg.eps), 0.0,
+                                 cfg.guidance_max_scale)
+                if cfg.guidance == "stored":
+                    m1_acc = m1_acc * scale    # Eq. (18) literally
+                    m_out = m1_acc
+                else:                  # "update": scale applied step only
+                    m_out = m1_acc * scale
+            else:
                 m_out = m1_acc
-            else:                            # "update": scale applied step only
-                m_out = m1_acc * scale
+            m1_new = m1_acc
         else:
-            m_out = m1_acc
-        m1_new = m1_acc
-    else:
-        m_out, m1_new = u_hat, None
+            m_out, m1_new = u_hat, None
 
-    return m_out, q_new, u_new, k_new, xi, m1_new, clip_active
+        return m_out, q_new, u_new, k_new, xi, m1_new, clip_active
 
 
 def _leaf_meta(w_shape, r_store: int, cfg: AdapproxConfig):
@@ -679,6 +684,7 @@ def _update_factored_bucket(gs, leaves, ws, idxs, step_key, step,
     return results
 
 
+@jax.named_scope("precondition")
 def _update_dense(g, leaf: F.DenseLeaf, cfg: AdapproxConfig):
     g32 = g.astype(jnp.float32)
     v = cfg.b2 * leaf.v + (1.0 - cfg.b2) * jnp.square(g32)
@@ -899,6 +905,7 @@ def scale_by_adapprox(cfg: AdapproxConfig) -> GradientTransformation:
                              leaves=leaves, telemetry=tel,
                              refresh_every=r_every, guards=gstate)
 
+    @jax.named_scope("adapprox")
     def update(grads, state: AdapproxState, params):
         step = state.step + 1              # paper counts from t = 1
         flat_p, treedef = jax.tree.flatten(params)

@@ -174,6 +174,7 @@ def scale_by_sketch(cfg: SketchConfig) -> GradientTransformation:
         return SketchState(step=jnp.zeros((), jnp.int32),
                            leaves=tuple(leaves), telemetry=tel)
 
+    @jax.named_scope("sketch")
     def update(grads, state: SketchState, params):
         del params
         step = state.step + 1

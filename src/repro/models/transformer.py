@@ -131,13 +131,14 @@ class TransformerLM:
         Returns logits (B, S, V) where S = F + S_txt."""
         cfg = self.cfg
         dt = _dtype(cfg)
-        x = params["embed"].astype(dt)[tokens]
-        if embeds is not None:
-            x = jnp.concatenate([embeds.astype(dt), x], axis=1)
-        if cfg.pos_embedding == "learned":
-            s = x.shape[1]
-            x = x + params["pos_embed"].astype(dt)[None, :s, :]
-        x = self.constrain(x)
+        with jax.named_scope("embed"):
+            x = params["embed"].astype(dt)[tokens]
+            if embeds is not None:
+                x = jnp.concatenate([embeds.astype(dt), x], axis=1)
+            if cfg.pos_embedding == "learned":
+                s = x.shape[1]
+                x = x + params["pos_embed"].astype(dt)[None, :s, :]
+            x = self.constrain(x)
 
         def body(carry, bp):
             x, aux = carry
@@ -145,17 +146,19 @@ class TransformerLM:
             return (x, aux + a), None
 
         body = _remat(cfg, body)
-        if cfg.scan_layers:
-            (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                       params["blocks"])
-        else:
-            aux = jnp.zeros((), jnp.float32)
-            for i in range(cfg.n_layers):
-                bp = jax.tree.map(lambda p: p[i], params["blocks"])
-                (x, aux), _ = body((x, aux), bp)
+        with jax.named_scope("blocks"):
+            if cfg.scan_layers:
+                (x, aux), _ = jax.lax.scan(
+                    body, (x, jnp.zeros((), jnp.float32)), params["blocks"])
+            else:
+                aux = jnp.zeros((), jnp.float32)
+                for i in range(cfg.n_layers):
+                    bp = jax.tree.map(lambda p: p[i], params["blocks"])
+                    (x, aux), _ = body((x, aux), bp)
 
-        x = L.apply_norm(cfg, params["final_norm"], x)
-        logits = self._lm_head(params, x)
+        with jax.named_scope("head"):
+            x = L.apply_norm(cfg, params["final_norm"], x)
+            logits = self._lm_head(params, x)
         return logits, aux
 
     def _lm_head(self, params, x):
@@ -174,12 +177,14 @@ class TransformerLM:
         if (not cfg.tie_embeddings and embeds is None
                 and cfg.vocab * tokens.shape[1] >= 2 ** 26):
             x, aux = self._hidden(params, tokens)
-            ce = L.fused_xent_from_hidden(x, params["lm_head"], tokens)
+            with jax.named_scope("head"):
+                ce = L.fused_xent_from_hidden(x, params["lm_head"], tokens)
         else:
             logits, aux = self.forward(params, tokens, embeds)
             n_front = 0 if embeds is None else embeds.shape[1]
-            txt_logits = logits[:, n_front:, :]
-            ce = L.softmax_xent(txt_logits[:, :-1, :], tokens[:, 1:])
+            with jax.named_scope("head"):
+                txt_logits = logits[:, n_front:, :]
+                ce = L.softmax_xent(txt_logits[:, :-1, :], tokens[:, 1:])
         total = ce + 0.01 * aux
         return total, {"loss": ce, "aux_loss": aux}
 
@@ -187,7 +192,8 @@ class TransformerLM:
         """Forward up to the final norm (no LM head)."""
         cfg = self.cfg
         dt = _dtype(cfg)
-        x = self.constrain(params["embed"].astype(dt)[tokens])
+        with jax.named_scope("embed"):
+            x = self.constrain(params["embed"].astype(dt)[tokens])
 
         def body(carry, bp):
             x, aux = carry
@@ -195,9 +201,11 @@ class TransformerLM:
             return (x, aux + a), None
 
         body = _remat(cfg, body)
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                   params["blocks"])
-        return L.apply_norm(cfg, params["final_norm"], x), aux
+        with jax.named_scope("blocks"):
+            (x, aux), _ = jax.lax.scan(
+                body, (x, jnp.zeros((), jnp.float32)), params["blocks"])
+        with jax.named_scope("head"):
+            return L.apply_norm(cfg, params["final_norm"], x), aux
 
     # -- serving -------------------------------------------------------------
     def init_cache(self, batch: int, cache_len: int) -> dict:
@@ -212,12 +220,13 @@ class TransformerLM:
                 embeds: Optional[jnp.ndarray] = None):
         cfg = self.cfg
         dt = _dtype(cfg)
-        x = params["embed"].astype(dt)[tokens]
-        if embeds is not None:
-            x = jnp.concatenate([embeds.astype(dt), x], axis=1)
-        if cfg.pos_embedding == "learned":
-            x = x + params["pos_embed"].astype(dt)[None, :x.shape[1], :]
-        x = self.constrain(x)
+        with jax.named_scope("embed"):
+            x = params["embed"].astype(dt)[tokens]
+            if embeds is not None:
+                x = jnp.concatenate([embeds.astype(dt), x], axis=1)
+            if cfg.pos_embedding == "learned":
+                x = x + params["pos_embed"].astype(dt)[None, :x.shape[1], :]
+            x = self.constrain(x)
 
         def body(x, xs):
             bp, kv = xs
@@ -229,9 +238,11 @@ class TransformerLM:
             return self.constrain(x + y), kv
 
         body = _remat(cfg, body)
-        x, kv = jax.lax.scan(body, x, (params["blocks"], cache["kv"]))
-        x = L.apply_norm(cfg, params["final_norm"], x)
-        logits = self._lm_head(params, x[:, -1:, :])
+        with jax.named_scope("blocks"):
+            x, kv = jax.lax.scan(body, x, (params["blocks"], cache["kv"]))
+        with jax.named_scope("head"):
+            x = L.apply_norm(cfg, params["final_norm"], x)
+            logits = self._lm_head(params, x[:, -1:, :])
         return logits, {"kv": kv, "pos": jnp.asarray(x.shape[1], jnp.int32)}
 
     def decode_step(self, params, cache, tokens):
@@ -239,10 +250,11 @@ class TransformerLM:
         cfg = self.cfg
         dt = _dtype(cfg)
         pos = cache["pos"]
-        x = params["embed"].astype(dt)[tokens]
-        if cfg.pos_embedding == "learned":
-            x = x + jax.lax.dynamic_slice_in_dim(
-                params["pos_embed"].astype(dt), pos, 1, axis=0)[None]
+        with jax.named_scope("embed"):
+            x = params["embed"].astype(dt)[tokens]
+            if cfg.pos_embedding == "learned":
+                x = x + jax.lax.dynamic_slice_in_dim(
+                    params["pos_embed"].astype(dt), pos, 1, axis=0)[None]
 
         def body(x, xs):
             bp, kv = xs
@@ -253,9 +265,11 @@ class TransformerLM:
             y, _ = self._moe_or_mlp(bp, h)
             return self.constrain(x + y), kv
 
-        x, kv = jax.lax.scan(body, x, (params["blocks"], cache["kv"]))
-        x = L.apply_norm(cfg, params["final_norm"], x)
-        logits = self._lm_head(params, x)
+        with jax.named_scope("blocks"):
+            x, kv = jax.lax.scan(body, x, (params["blocks"], cache["kv"]))
+        with jax.named_scope("head"):
+            x = L.apply_norm(cfg, params["final_norm"], x)
+            logits = self._lm_head(params, x)
         return logits, {"kv": kv, "pos": pos + 1}
 
     # -- paged serving (block-table KV cache; see serve/kv_cache.py) --------
@@ -279,10 +293,12 @@ class TransformerLM:
         cfg = self.cfg
         dt = _dtype(cfg)
         c = tokens.shape[1]
-        x = params["embed"].astype(dt)[tokens]
-        if cfg.pos_embedding == "learned":
-            x = x + params["pos_embed"].astype(dt)[p0 + jnp.arange(c)][None]
-        x = self.constrain(x)
+        with jax.named_scope("embed"):
+            x = params["embed"].astype(dt)[tokens]
+            if cfg.pos_embedding == "learned":
+                x = x + params["pos_embed"].astype(dt)[
+                    p0 + jnp.arange(c)][None]
+            x = self.constrain(x)
 
         def body(x, xs):
             bp, (pk, pv) = xs
@@ -294,11 +310,13 @@ class TransformerLM:
             y, _ = self._moe_or_mlp(bp, h)
             return self.constrain(x + y), (pk, pv)
 
-        x, kv = jax.lax.scan(body, x, (params["blocks"],
-                                       (pool["k"], pool["v"])))
-        x = L.apply_norm(cfg, params["final_norm"], x)
-        xlast = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
-        logits = self._lm_head(params, xlast)
+        with jax.named_scope("blocks"):
+            x, kv = jax.lax.scan(body, x, (params["blocks"],
+                                           (pool["k"], pool["v"])))
+        with jax.named_scope("head"):
+            x = L.apply_norm(cfg, params["final_norm"], x)
+            xlast = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
+            logits = self._lm_head(params, xlast)
         return logits, {"k": kv[0], "v": kv[1]}
 
     def decode_paged(self, params, pool, tokens, block_tables, positions):
@@ -309,9 +327,10 @@ class TransformerLM:
         masked out host-side by the engine."""
         cfg = self.cfg
         dt = _dtype(cfg)
-        x = params["embed"].astype(dt)[tokens]
-        if cfg.pos_embedding == "learned":
-            x = x + params["pos_embed"].astype(dt)[positions][:, None, :]
+        with jax.named_scope("embed"):
+            x = params["embed"].astype(dt)[tokens]
+            if cfg.pos_embedding == "learned":
+                x = x + params["pos_embed"].astype(dt)[positions][:, None, :]
 
         def body(x, xs):
             bp, (pk, pv) = xs
@@ -323,8 +342,10 @@ class TransformerLM:
             y, _ = self._moe_or_mlp(bp, h)
             return self.constrain(x + y), (pk, pv)
 
-        x, kv = jax.lax.scan(body, x, (params["blocks"],
-                                       (pool["k"], pool["v"])))
-        x = L.apply_norm(cfg, params["final_norm"], x)
-        logits = self._lm_head(params, x)
+        with jax.named_scope("blocks"):
+            x, kv = jax.lax.scan(body, x, (params["blocks"],
+                                           (pool["k"], pool["v"])))
+        with jax.named_scope("head"):
+            x = L.apply_norm(cfg, params["final_norm"], x)
+            logits = self._lm_head(params, x)
         return logits, {"k": kv[0], "v": kv[1]}

@@ -334,6 +334,7 @@ class ContinuousEngine:
         self.completed = 0
         self._ready: "deque[Request]" = deque()
         self._rr = 0                                # prefill round-robin
+        self._t0 = time.monotonic()     # run() start: engine-relative zero
         self._above_watermark = False
         self.set_tracer(tracer)
 
@@ -358,8 +359,10 @@ class ContinuousEngine:
         """Attach (or with ``None`` detach) a repro.telemetry Tracer:
         every request gets a span waterfall (queued / admitted /
         prefill_chunk / decode under a per-request root span) joined to
-        its serve events by trace id, engine steps become spans on a
-        per-engine trace, and — when the tracer carries a registry —
+        its serve events by trace id, engine steps (with their phases as
+        children, see :meth:`step`) and the run loop's ``intake`` and
+        ``idle_wait`` become spans on a per-engine trace, and — when the
+        tracer carries a registry —
         request/token counters and a latency histogram are kept."""
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self._tracing = tracer is not None
@@ -463,10 +466,11 @@ class ContinuousEngine:
             slot.table.blocks.extend(ids)
 
     # -- prefill -----------------------------------------------------------
-    def _prefill_one(self, now: float) -> bool:
+    def _prefill_one(self, now: float, sp) -> bool:
         """Run ONE bucketed prompt chunk for the next prefilling slot
         (round-robin) — chunked prefill interleaves with decode instead
-        of stalling it."""
+        of stalling it.  ``sp``: the step's span, whose
+        ``prefill_tokens`` counts the chunk's prompt tokens."""
         n = len(self.slots)
         for off in range(n):
             slot = self.slots[(self._rr + off) % n]
@@ -486,19 +490,21 @@ class ContinuousEngine:
                 parent=ROOT_SPAN, attrs={"uid": req.uid})
         real = min(self.cfg.prefill_chunk, len(req.prompt) - p0)
         padded = _bucket(real, self.cfg.prefill_chunk)
-        self._grow(slot, p0 + padded)
-        chunk = np.full((1, padded), self.cfg.pad_id, np.int32)
-        chunk[0, :real] = req.prompt[p0:p0 + real]
-        tw0 = time.monotonic()
-        tok, self.pool = self._prefill_jit(
-            self.params, self.pool, chunk, slot.table.padded(self.nbt),
-            jnp.asarray(p0, jnp.int32), jnp.asarray(real - 1, jnp.int32))
-        if traced:
-            dur = time.monotonic() - tw0   # host dispatch wall time
-            self.tracer.record(
-                "prefill_chunk", self.tracer.now() - dur, dur, req.trace,
-                parent=ROOT_SPAN,
-                attrs={"uid": req.uid, "p0": p0, "tokens": real})
+        with self.tracer.span("prefill_dispatch"):
+            self._grow(slot, p0 + padded)
+            chunk = np.full((1, padded), self.cfg.pad_id, np.int32)
+            chunk[0, :real] = req.prompt[p0:p0 + real]
+            tw0 = time.monotonic()
+            tok, self.pool = self._prefill_jit(
+                self.params, self.pool, chunk, slot.table.padded(self.nbt),
+                jnp.asarray(p0, jnp.int32), jnp.asarray(real - 1, jnp.int32))
+            if traced:
+                dur = time.monotonic() - tw0   # host dispatch wall time
+                self.tracer.record(
+                    "prefill_chunk", self.tracer.now() - dur, dur, req.trace,
+                    parent=ROOT_SPAN,
+                    attrs={"uid": req.uid, "p0": p0, "tokens": real})
+        sp.set(prefill_tokens=real)
         slot.prompt_done += real
         if slot.prompt_done < len(req.prompt):
             return True
@@ -508,15 +514,17 @@ class ContinuousEngine:
         if req.max_new_tokens <= 0:
             self._finish(slot, now)     # zero budget emits nothing
             return True
-        tok = int(tok)
+        with self.tracer.span("prefill_readback"):
+            tok = int(tok)
+        t_tok = _now(self._t0)          # the token is on the host now
         req.out_tokens.append(tok)
-        req.first_token_s = now
+        req.first_token_s = t_tok
         self.tokens_emitted += 1
-        self._emit("first_token", now, uid=req.uid,
-                   ttft_s=now - req.arrival_s, **_tr(req))
+        self._emit("first_token", t_tok, uid=req.uid,
+                   ttft_s=t_tok - req.arrival_s, **_tr(req))
         if ((self.cfg.eos_id is not None and tok == self.cfg.eos_id)
                 or req.max_new_tokens == 1):
-            self._finish(slot, now)
+            self._finish(slot, t_tok)
         else:
             slot.phase = "decode"
             slot.last_token = tok
@@ -524,36 +532,43 @@ class ContinuousEngine:
         return True
 
     # -- decode ------------------------------------------------------------
-    def _decode_all(self, now: float) -> bool:
+    def _decode_all(self, sp) -> bool:
         """One token for every decoding slot; idle/prefilling rows are
-        parked on the null block and their outputs dropped."""
+        parked on the null block and their outputs dropped.  ``sp``: the
+        step's span, whose ``decode_rows`` counts the rows decoded."""
         rows = [i for i, s in enumerate(self.slots) if s.phase == "decode"]
         if not rows:
             return False
-        n = self.cfg.slots
-        tokens = np.zeros((n, 1), np.int32)
-        tables = np.full((n, self.nbt), NULL_BLOCK, np.int32)
-        positions = np.zeros((n,), np.int32)
-        for i in rows:
-            slot = self.slots[i]
-            self._grow(slot, slot.length + 1)
-            tokens[i, 0] = slot.last_token
-            tables[i] = slot.table.padded(self.nbt)
-            positions[i] = slot.length
-        toks, self.pool = self._decode_jit(self.params, self.pool, tokens,
-                                           tables, positions)
-        toks = np.asarray(toks)
-        for i in rows:
-            slot = self.slots[i]
-            tok = int(toks[i])
-            slot.req.out_tokens.append(tok)
-            self.tokens_emitted += 1
-            slot.length += 1
-            slot.budget -= 1
-            slot.last_token = tok
-            if slot.budget <= 0 or (self.cfg.eos_id is not None
-                                    and tok == self.cfg.eos_id):
-                self._finish(slot, now)
+        with self.tracer.span("decode_prepare"):
+            n = self.cfg.slots
+            tokens = np.zeros((n, 1), np.int32)
+            tables = np.full((n, self.nbt), NULL_BLOCK, np.int32)
+            positions = np.zeros((n,), np.int32)
+            for i in rows:
+                slot = self.slots[i]
+                self._grow(slot, slot.length + 1)
+                tokens[i, 0] = slot.last_token
+                tables[i] = slot.table.padded(self.nbt)
+                positions[i] = slot.length
+        with self.tracer.span("decode_dispatch"):
+            toks, self.pool = self._decode_jit(self.params, self.pool,
+                                               tokens, tables, positions)
+        with self.tracer.span("decode_readback"):
+            toks = np.asarray(toks)
+        t_tok = _now(self._t0)          # the tokens are on the host now
+        sp.set(decode_rows=len(rows))
+        with self.tracer.span("decode_commit"):
+            for i in rows:
+                slot = self.slots[i]
+                tok = int(toks[i])
+                slot.req.out_tokens.append(tok)
+                self.tokens_emitted += 1
+                slot.length += 1
+                slot.budget -= 1
+                slot.last_token = tok
+                if slot.budget <= 0 or (self.cfg.eos_id is not None
+                                        and tok == self.cfg.eos_id):
+                    self._finish(slot, t_tok)
         return True
 
     # -- lifecycle ---------------------------------------------------------
@@ -608,12 +623,20 @@ class ContinuousEngine:
 
     def step(self, now: float) -> bool:
         """One scheduler step: admit, one prefill chunk, one decode step
-        for every live row.  Returns whether any work ran."""
-        with self.tracer.span("engine_step",
-                              trace=self._engine_trace) as sp:
-            self._admit(now)
-            did = self._prefill_one(now)
-            did = self._decode_all(now) or did
+        for every live row.  Returns whether any work ran.
+
+        Traced, the ``engine_step`` span holds live children ``admit``,
+        ``prefill_dispatch``, ``prefill_readback`` (a prompt's last
+        chunk), ``decode_prepare``, ``decode_dispatch``,
+        ``decode_readback`` and ``decode_commit``, and counts
+        ``prefill_tokens`` and ``decode_rows``.  First tokens and
+        finishes are stamped after their readback."""
+        with self.tracer.span("engine_step", trace=self._engine_trace,
+                              prefill_tokens=0, decode_rows=0) as sp:
+            with self.tracer.span("admit"):
+                self._admit(now)
+            did = self._prefill_one(now, sp)
+            did = self._decode_all(sp) or did
             sp.set(step=self.steps + 1)
         self.steps += 1
         if self.sink is not None and self.steps % self.cfg.stats_every == 0:
@@ -634,7 +657,7 @@ class ContinuousEngine:
         full bounded queue (``max_queue``) are load-shed (``rejected``)."""
         for r in requests:
             self._validate(r)
-        t0 = time.monotonic()
+        t0 = self._t0 = time.monotonic()
         if self._tracing:
             self._toff = self.tracer.now() - _now(t0)
         if arrivals is None:
@@ -646,25 +669,33 @@ class ContinuousEngine:
         while pending or self._ready \
                 or any(s.phase != "idle" for s in self.slots):
             now = _now(t0)
-            while pending and pending[0][0] <= now:
-                _, req = pending.popleft()
-                if self._tracing and req.trace is None:
-                    req.trace = self.tracer.new_trace("req")
-                if 0 < self.cfg.max_queue <= len(self._ready):
-                    req.rejected = True
-                    req.done = True
-                    req.done_s = now
-                    self._emit("reject", now, uid=req.uid,
-                               queue_depth=len(self._ready), **_tr(req))
-                    if self._tracing and req.trace:
-                        self.tracer.record(
-                            "queued", req.arrival_s + self._toff,
-                            max(now - req.arrival_s, 0.0), req.trace,
-                            parent=ROOT_SPAN, attrs={"uid": req.uid})
-                        self._record_waterfall(req, now)
-                    continue
-                self._ready.append(req)
+            with self.tracer.span("intake", trace=self._engine_trace):
+                self._intake(pending, now)
             if not self.step(now) and not self._ready:
                 if pending:
-                    time.sleep(max(pending[0][0] - _now(t0), 0.0))
+                    with self.tracer.span("idle_wait",
+                                          trace=self._engine_trace):
+                        time.sleep(max(pending[0][0] - _now(t0), 0.0))
         return requests
+
+    def _intake(self, pending: deque, now: float) -> None:
+        """Move every request due by ``now`` to the ready queue, or shed
+        it when the bounded queue is full."""
+        while pending and pending[0][0] <= now:
+            _, req = pending.popleft()
+            if self._tracing and req.trace is None:
+                req.trace = self.tracer.new_trace("req")
+            if 0 < self.cfg.max_queue <= len(self._ready):
+                req.rejected = True
+                req.done = True
+                req.done_s = now
+                self._emit("reject", now, uid=req.uid,
+                           queue_depth=len(self._ready), **_tr(req))
+                if self._tracing and req.trace:
+                    self.tracer.record(
+                        "queued", req.arrival_s + self._toff,
+                        max(now - req.arrival_s, 0.0), req.trace,
+                        parent=ROOT_SPAN, attrs={"uid": req.uid})
+                    self._record_waterfall(req, now)
+                continue
+            self._ready.append(req)

@@ -1,8 +1,8 @@
 """Span tracing: waterfalls for train steps and serve requests.
 
-Zero-dependency (stdlib-only) span API over the existing async JSONL
-sink: context-manager spans with monotonic clocks, trace/span ids and a
-thread-local span stack record ``kind="span"`` events through the same
+Span API over the existing async JSONL sink: context-manager spans with
+monotonic clocks, trace/span ids and a thread-local span stack record
+``kind="span"`` events through the same
 :class:`~repro.telemetry.sink.TelemetrySink` every other telemetry kind
 uses — one stream, one schema, one ``validate_dir``.  Spans are
 HOST-SIDE ONLY: nothing here runs inside jit, so the bitwise
@@ -14,12 +14,19 @@ Two recording styles:
 
   * ``with tracer.span("data_wait"): ...`` — live spans.  Nesting is
     tracked per thread: an inner span's ``parent`` is the enclosing
-    span's id, and an inner span inherits the enclosing trace id.
+    span's id, and an inner span inherits the enclosing trace id.  While
+    it is open, a live span also holds a ``jax.profiler.TraceAnnotation``
+    of the same name, so under a running profiler it lands on the
+    profile's host plane, on the profiler's clock, beside the device
+    ops.  jax is imported when the first :class:`Tracer` is made, never
+    by :class:`NullTracer`.
   * ``tracer.record(name, t0_s, dur_s, trace, ...)`` — after-the-fact
     spans for lifecycles whose phases are only known at the end (a serve
     request's queued/admitted/prefill/decode waterfall).  The serving
     engines use the fixed span id ``"root"`` for the per-request
-    ``"request"`` root and parent every phase under it.
+    ``"request"`` root and parent every phase under it.  These rebuild
+    phases rather than time host work, so they go to the JSONL stream
+    only, never to the profiler.
 
 Trace-id join contract with ``kind="serve"``: the continuous/wave
 engines stamp each request's trace id into its per-request serve events
@@ -124,8 +131,10 @@ class Tracer:
     ``span_duration_seconds`` histogram labelled by span name)."""
 
     def __init__(self, sink=None, registry=None):
+        from jax import profiler
         self.sink = sink
         self.registry = registry
+        self._profiler = profiler
         self._epoch = time.monotonic()
         self._ids = itertools.count()
         # distinct per process so streams from restarts never collide
@@ -171,9 +180,12 @@ class Tracer:
         handle = SpanHandle(name, trace, sid, self.now(), parent, dict(attrs))
         self._open[sid] = handle
         stack.append((trace, sid))
+        ann = self._profiler.TraceAnnotation(name)
+        ann.__enter__()
         try:
             yield handle
         finally:
+            ann.__exit__(None, None, None)
             stack.pop()
             # drain_open may have already emitted this span (truncated)
             # from the preemption handler: the pop decides exactly one

@@ -85,14 +85,19 @@ def build_train_step(model, opt,
         return grads, loss, {"loss": loss}
 
     def train_step(state: TrainState, batch):
-        grads, loss, metrics = compute_grads(state.params, batch)
-        if grad_clip_norm is not None:
-            norm = global_norm(grads)
-            scale = jnp.minimum(1.0, grad_clip_norm / (norm + 1e-12))
-            grads = jax.tree.map(lambda g: g * scale, grads)
-            metrics = dict(metrics, grad_norm=norm)
-        updates, opt_state = opt.update(grads, state.opt_state, state.params)
-        params = apply_updates(state.params, updates)
+        # named scopes tag the step's device ops in a profile (metadata
+        # only: the compiled program is unchanged)
+        with jax.named_scope("loss_and_grads"):
+            grads, loss, metrics = compute_grads(state.params, batch)
+        with jax.named_scope("optimizer"):
+            if grad_clip_norm is not None:
+                norm = global_norm(grads)
+                scale = jnp.minimum(1.0, grad_clip_norm / (norm + 1e-12))
+                grads = jax.tree.map(lambda g: g * scale, grads)
+                metrics = dict(metrics, grad_norm=norm)
+            updates, opt_state = opt.update(grads, state.opt_state,
+                                            state.params)
+            params = apply_updates(state.params, updates)
         new_state = TrainState(params=params, opt_state=opt_state,
                                step=state.step + 1)
         metrics = dict(metrics, loss=loss, step=state.step)

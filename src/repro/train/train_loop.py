@@ -88,7 +88,9 @@ def train(model, opt: GradientTransformation, data_cfg: DataConfig,
     emits a host-side ``train_step`` span with ``data_wait`` /
     ``step_dispatch`` / ``device_sync`` children, attributed
     refresh-vs-fold from the in-jit snapshot counters when the optimizer
-    collects them; checkpoint saves/restores get their own spans.  Spans
+    collects them, then a ``step_end`` span over the bookkeeping after
+    it (telemetry, registry, log readback, ``metric_hook``, the
+    checkpoint test); checkpoint saves/restores get their own spans.  Spans
     never enter jit — the step function is untouched, so the
     bitwise-default-chain contract holds with tracing on.  The
     preemption handler chain drains open spans (``"truncated": true``)
@@ -234,42 +236,44 @@ def train(model, opt: GradientTransformation, data_cfg: DataConfig,
                     if phase is not None:
                         step_span.set(phase=phase)
 
-            if telemetry is not None:
-                # fetch snapshots / emit events / retune cadences; the
-                # loop already synced on the loss, so this adds no device
-                # round-trip beyond the scalar fetch
-                state = telemetry.on_step(step + 1, state)
+            with tr.span("step_end", trace=run_trace, step=step + 1):
+                if telemetry is not None:
+                    # fetch snapshots / emit events / retune cadences;
+                    # the loop already synced on the loss, so this adds
+                    # no device round-trip beyond the scalar fetch
+                    state = telemetry.on_step(step + 1, state)
 
-            if ckpt is not None and install_signal_handler:
-                latest["snap"] = (state, step + 1, _meta())
+                if ckpt is not None and install_signal_handler:
+                    latest["snap"] = (state, step + 1, _meta())
 
-            if reg is not None:
-                reg.counter("train_steps_total",
-                            help="train steps completed").inc()
-                reg.histogram("train_step_seconds",
-                              help="wall time per train step").observe(dt)
-                if (step + 1) % metrics_every == 0:
-                    reg.gauge("train_loss",
-                              help="loss at the last snapshot").set(
-                                  float(np.asarray(metrics["loss"])))
-                    if metric_sink is not None:
-                        metric_sink.emit(reg.snapshot(
-                            t_s=time.monotonic() - loop_t0, step=step + 1))
+                if reg is not None:
+                    reg.counter("train_steps_total",
+                                help="train steps completed").inc()
+                    reg.histogram("train_step_seconds",
+                                  help="wall time per train step").observe(dt)
+                    if (step + 1) % metrics_every == 0:
+                        reg.gauge("train_loss",
+                                  help="loss at the last snapshot").set(
+                                      float(np.asarray(metrics["loss"])))
+                        if metric_sink is not None:
+                            metric_sink.emit(reg.snapshot(
+                                t_s=time.monotonic() - loop_t0,
+                                step=step + 1))
 
-            if (step + 1) % loop_cfg.log_every == 0 or step == start_step:
-                m = {k: float(np.asarray(v)) for k, v in metrics.items()}
-                m["step_time_s"] = dt
-                m["step"] = step + 1
-                history.append(m)
-                if metric_hook:
-                    metric_hook(step + 1, m)
-                log.info("step %d loss %.4f (%.3fs)", step + 1,
-                         m.get("loss", float("nan")), dt)
+                if (step + 1) % loop_cfg.log_every == 0 or step == start_step:
+                    m = {k: float(np.asarray(v)) for k, v in metrics.items()}
+                    m["step_time_s"] = dt
+                    m["step"] = step + 1
+                    history.append(m)
+                    if metric_hook:
+                        metric_hook(step + 1, m)
+                    log.info("step %d loss %.4f (%.3fs)", step + 1,
+                             m.get("loss", float("nan")), dt)
 
-            if ckpt is not None and ckpt.should_save(step + 1):
-                with tr.span("checkpoint_save", trace=run_trace,
-                             step=step + 1):
-                    ckpt.save(state, step + 1, extra_meta=_meta())
+                if ckpt is not None and ckpt.should_save(step + 1):
+                    with tr.span("checkpoint_save", trace=run_trace,
+                                 step=step + 1):
+                        ckpt.save(state, step + 1, extra_meta=_meta())
     finally:
         data.close()
         if ckpt is not None:

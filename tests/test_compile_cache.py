@@ -14,9 +14,17 @@ import jax, jax.numpy as jnp
 from repro.compile_cache import DEFAULT_DIR, enable_compile_cache
 d = enable_compile_cache()
 assert jax.config.jax_compilation_cache_dir == d, d
-if sys.argv[1] == "compile":
+if sys.argv[1] in ("compile", "scopes"):
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.jit(lambda x: jnp.sin(x) @ x.T)(jnp.ones((8, 8))).block_until_ready()
+if sys.argv[1] == "scopes":
+    def scoped(name):
+        def step(x):
+            with jax.named_scope(name):
+                return jnp.cos(x) @ x.T
+        return step
+    for name in ("optimizer", "head"):
+        jax.jit(scoped(name))(jnp.ones((8, 8))).block_until_ready()
 print("CACHE_DIR=" + d)
 print("DEFAULT_DIR=" + str(DEFAULT_DIR))
 """
@@ -45,3 +53,13 @@ def test_env_dir_receives_cache_entries(tmp_path):
 def test_default_dir_is_fixed_inside_checkout():
     got = _run(None, "config-only")
     assert got["CACHE_DIR"] == got["DEFAULT_DIR"] == str(REPO / ".jax_cache")
+
+
+def test_named_scopes_get_entries_of_their_own(tmp_path):
+    """Two programs that differ only in a named scope (metadata) are two
+    cache entries, so each executable keeps its own op names."""
+    def entries(d):
+        return sum(p.name.endswith("-cache") for p in d.iterdir())
+    _run(tmp_path / "plain", "compile")
+    _run(tmp_path / "scoped", "scopes")
+    assert entries(tmp_path / "scoped") == entries(tmp_path / "plain") + 2

@@ -391,6 +391,83 @@ class TestWaterfalls:
         for name in ("request", "queued", "prefill"):
             assert sum(1 for s in spans if s["name"] == name) == len(reqs)
 
+    def test_engine_step_records_its_phases(self, model_and_params):
+        """One step that prefills a prompt's last chunk and decodes: every
+        phase is a live child span of ``engine_step``, which counts the
+        prompt tokens prefilled and the rows decoded."""
+        from repro.telemetry import Tracer
+
+        class Sink:
+            events = []
+
+            def emit(self, ev):
+                self.events.append(ev)
+
+        model, params = model_and_params
+        eng = _cont(model, params)
+        eng.set_tracer(Tracer(sink=Sink()))
+        eng._ready.extend(_reqs([5, 20], [4, 4]))
+        eng.step(0.0)        # admits both, prefills and decodes the first
+        Sink.events.clear()
+        eng.step(0.1)        # the second's only chunk, then both decode
+        step, = [e for e in Sink.events if e["name"] == "engine_step"]
+        kids = [e["name"] for e in Sink.events
+                if e.get("parent") == step["span"]]
+        assert sorted(kids) == sorted([
+            "admit", "prefill_dispatch", "prefill_readback",
+            "decode_prepare", "decode_dispatch", "decode_readback",
+            "decode_commit"])
+        assert step["attrs"] == {"prefill_tokens": 20, "decode_rows": 2}
+        assert step["step"] == 2
+
+    def test_tokens_are_stamped_after_their_readback(
+            self, model_and_params, tmp_path):
+        """A request's ``decode`` span runs from its first token to its
+        finish, both stamped once the token is on the host: it starts
+        after its prompt's ``prefill_readback`` and ends no earlier than
+        its last ``decode_readback`` (one per token after the first)."""
+        model, params = model_and_params
+        reqs = _reqs([5, 17, 33, 9, 40], [6, 3, 5, 2, 4])
+        events, _ = self._run_traced(tmp_path, _cont(model, params), reqs,
+                                     arrivals=[0.0, 0.0, 0.05, 0.1, 0.1])
+        spans = [e for e in events if e["kind"] == "span"]
+        reads = sorted((e for e in spans if e["name"] == "decode_readback"),
+                       key=lambda e: e["t0_s"])
+        pre = [e for e in spans if e["name"] == "prefill_readback"]
+        eps = 2e-6                   # the stream's microsecond rounding
+        decodes = [e for e in spans if e["name"] == "decode"]
+        assert len(decodes) == len(reqs)
+        for d in decodes:
+            tokens = next(e["attrs"]["tokens"] for e in spans
+                          if e["trace"] == d["trace"]
+                          and e["name"] == "request")
+            assert any(abs(p["t0_s"] + p["dur_s"] - d["t0_s"]) < 1e-3
+                       and p["t0_s"] + p["dur_s"] <= d["t0_s"] + eps
+                       for p in pre)
+            mine = [r for r in reads if r["t0_s"] >= d["t0_s"] - eps]
+            last = mine[tokens - 2]
+            assert d["t0_s"] + d["dur_s"] >= last["t0_s"] + last["dur_s"] \
+                - eps
+
+    def test_traceview_check_passes_on_engine_run(self, model_and_params,
+                                                   tmp_path):
+        import os
+        import subprocess
+        import sys
+        from pathlib import Path
+        model, params = model_and_params
+        self._run_traced(tmp_path, _cont(model, params),
+                         _reqs([5, 40, 9], [3, 4, 1]))
+        root = Path(__file__).resolve().parent.parent
+        env = dict(os.environ, PYTHONPATH=str(root / "src"))
+        p = subprocess.run([sys.executable, str(root / "tools" /
+                                                "traceview.py"),
+                            str(tmp_path), "--check"],
+                           env=env, capture_output=True, text=True,
+                           timeout=120)
+        assert p.returncode == 0, p.stdout + p.stderr
+        assert "engine_step" in p.stdout and "decode_readback" in p.stdout
+
     def test_untraced_run_emits_no_spans(self, model_and_params, tmp_path):
         """tracer=None (the default) keeps the serve stream span-free —
         tracing is strictly opt-in."""
