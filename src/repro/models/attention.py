@@ -251,11 +251,17 @@ def attn_decode(cfg, p, x, cache: KVCache,
 
 
 # -- paged (block-table) KV cache ---------------------------------------------
-# One layer's pool is (num_blocks, block_size, KV, dh); a sequence owns an
-# ordered list of block ids (its block table) and a scalar position.  The
-# attention read gathers the pool through the table into the LOGICAL dense
-# layout (B, n_blocks_per_slot * block_size, KV, dh) and runs the exact
-# same ``_sdpa`` reduction as the dense cache — positions at or beyond the
+# The pool holds every layer: (L, num_blocks, block_size, KV*dh), lane-dense
+# (a minor dimension of dh alone would pad to the 128-lane tile, and the
+# compiler would then store the pool with num_blocks minor and relayout
+# it on every access).  The layer loop carries the whole pool: each layer
+# writes its new tokens with one scatter at [layer, block, offset] and
+# reads with one gather pool[layer, block_tables], so the pool is updated
+# in place and no layer slice of it is ever materialised.  A sequence owns
+# an ordered list of block ids (its block table) and a scalar position.
+# The read gathers through the table into the LOGICAL dense layout (B,
+# n_blocks_per_slot * block_size, KV, dh) and runs the exact same
+# ``_sdpa`` reduction as the dense cache — positions at or beyond the
 # per-row length are masked to NEG_INF, whose softmax weight underflows to
 # exactly 0.0, so stale data in padded/recycled blocks can never leak into
 # the output.  When the logical length equals ``cache_len`` this is
@@ -265,60 +271,68 @@ def attn_decode(cfg, p, x, cache: KVCache,
 # of reserving cache_len worst-case each.
 
 
-def _paged_gather(pool: jnp.ndarray, block_tables: jnp.ndarray):
-    """pool: (NB, bs, KV, dh); block_tables: (B, nbt) -> (B, nbt*bs, KV, dh)."""
-    g = pool[block_tables]                       # (B, nbt, bs, KV, dh)
-    b, nbt, bs = g.shape[:3]
-    return g.reshape(b, nbt * bs, *g.shape[3:])
+def _paged_write(pool, layer, bids, offs, x):
+    """pool: (L, NB, bs, KV*dh); x: (N, KV, dh) written at [layer,
+    bids[i], offs[i]]."""
+    return pool.at[layer, bids, offs].set(
+        x.reshape(x.shape[0], -1).astype(pool.dtype))
 
 
-def attn_decode_paged(cfg, p, x, pk, pv, block_tables, positions):
+def _paged_gather(pool, layer, block_tables, n_kv: int):
+    """pool: (L, NB, bs, KV*dh); block_tables: (B, nbt) ->
+    (B, nbt*bs, KV, dh), layer ``layer``'s rows in logical order."""
+    g = pool[layer, block_tables]                # (B, nbt, bs, KV*dh)
+    b, nbt, bs, w = g.shape
+    return g.reshape(b, nbt * bs, n_kv, w // n_kv)
+
+
+def attn_decode_paged(cfg, p, x, pk, pv, layer, block_tables, positions):
     """One-token step against the block pool, per-row positions.
 
-    x: (B, 1, D); pk/pv: (NB, bs, KV, dh) one layer's pool;
-    block_tables: (B, nbt) int32; positions: (B,) int32 — row i's token
-    lands at logical position positions[i] (physical block
-    block_tables[i, positions[i] // bs], offset positions[i] % bs).
-    Rows parked on the null block (table all zeros, position 0) scatter
-    garbage into block 0, which only ever appears masked — see
-    serve/kv_cache.py for why block 0 is reserved.
+    x: (B, 1, D); pk/pv: (L, NB, bs, KV*dh) the whole pool; layer: ()
+    int32 this layer's index into it; block_tables: (B, nbt) int32;
+    positions: (B,) int32 — row i's token lands at logical position
+    positions[i] (physical block block_tables[i, positions[i] // bs],
+    offset positions[i] % bs).  Rows parked on the null block (table all
+    zeros, position 0) scatter garbage into block 0, which only ever
+    appears masked — see serve/kv_cache.py for why block 0 is reserved.
     """
-    b = x.shape[0]
-    bs = pk.shape[1]
+    bs = pk.shape[2]
     q, k, v = _project_qkv(cfg, p, x, positions[:, None])
     bids = jnp.take_along_axis(block_tables, (positions // bs)[:, None],
                                axis=1)[:, 0]                    # (B,)
     offs = positions % bs
-    pk = pk.at[bids, offs].set(k[:, 0].astype(pk.dtype))
-    pv = pv.at[bids, offs].set(v[:, 0].astype(pv.dtype))
-    kall = _paged_gather(pk, block_tables)
-    vall = _paged_gather(pv, block_tables)
+    pk = _paged_write(pk, layer, bids, offs, k[:, 0])
+    pv = _paged_write(pv, layer, bids, offs, v[:, 0])
+    kall = _paged_gather(pk, layer, block_tables, cfg.n_kv_heads)
+    vall = _paged_gather(pv, layer, block_tables, cfg.n_kv_heads)
     s = kall.shape[1]
     mask = jnp.arange(s)[None, None, :] <= positions[:, None, None]
     out = _sdpa(cfg, q, kall, vall, mask)
     return out @ p["wo"].astype(x.dtype), pk, pv
 
 
-def attn_prefill_paged(cfg, p, x, pk, pv, block_table, p0):
+def attn_prefill_paged(cfg, p, x, pk, pv, layer, block_table, p0):
     """Causal attention over ONE prompt chunk, writing through the block
     table.  x: (1, C, D) — chunk tokens at logical positions
-    p0..p0+C-1; block_table: (nbt,) int32 for this one slot; p0: ()
-    int32.  The chunk attends to everything already in the slot's blocks
-    (earlier chunks) plus itself, causally.  Chunk padding past the real
-    prompt length writes garbage k/v at positions the NEXT chunk (or
-    decode) overwrites before they are ever unmasked, so bucketed chunk
-    shapes stay compile-once without a pad mask.
+    p0..p0+C-1; pk/pv and layer as in ``attn_decode_paged``;
+    block_table: (nbt,) int32 for this one slot; p0: () int32.  The chunk
+    attends to everything already in the slot's blocks (earlier chunks)
+    plus itself, causally.  Chunk padding past the real prompt length
+    writes garbage k/v at positions the NEXT chunk (or decode) overwrites
+    before they are ever unmasked, so bucketed chunk shapes stay
+    compile-once without a pad mask.
     """
     _, c, _ = x.shape
-    bs = pk.shape[1]
+    bs = pk.shape[2]
     tok_pos = p0 + jnp.arange(c)
     q, k, v = _project_qkv(cfg, p, x, tok_pos[None, :])
     bids = block_table[tok_pos // bs]                           # (C,)
     offs = tok_pos % bs
-    pk = pk.at[bids, offs].set(k[0].astype(pk.dtype))
-    pv = pv.at[bids, offs].set(v[0].astype(pv.dtype))
-    kall = _paged_gather(pk, block_table[None, :])
-    vall = _paged_gather(pv, block_table[None, :])
+    pk = _paged_write(pk, layer, bids, offs, k[0])
+    pv = _paged_write(pv, layer, bids, offs, v[0])
+    kall = _paged_gather(pk, layer, block_table[None, :], cfg.n_kv_heads)
+    vall = _paged_gather(pv, layer, block_table[None, :], cfg.n_kv_heads)
     s = kall.shape[1]
     mask = jnp.arange(s)[None, None, :] <= tok_pos[None, :, None]
     out = _sdpa(cfg, q, kall, vall, mask)

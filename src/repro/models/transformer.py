@@ -275,14 +275,38 @@ class TransformerLM:
     # -- paged serving (block-table KV cache; see serve/kv_cache.py) --------
     def init_paged_cache(self, num_blocks: int, block_size: int) -> dict:
         """Block pool shared by every slot: {"k","v"} of shape
-        (L, num_blocks, block_size, KV, dh).  Block tables / positions are
-        NOT part of the cache — the engine owns them host-side and passes
-        them per call, so the pool pytree alone is donated/recycled."""
+        (L, num_blocks, block_size, KV*dh), heads and head dim flattened
+        into one lane-dense minor dimension (models/attention.py).  Block
+        tables / positions are NOT part of the cache — the engine owns
+        them host-side and passes them per call, so the pool pytree alone
+        is donated/recycled."""
         cfg = self.cfg
         dt = _dtype(cfg)
-        hd = cfg.resolved_head_dim
-        shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads, hd)
+        width = cfg.n_kv_heads * cfg.resolved_head_dim
+        shape = (cfg.n_layers, num_blocks, block_size, width)
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+
+    def _paged_blocks(self, params, pool, x, attend):
+        """The layer loop of the paged paths.  The whole pool rides in
+        the scan's carry with the layer index, so ``attend(p_attn, h, pk,
+        pv, layer) -> (out, pk, pv)`` updates it in place; only the block
+        params are scanned over."""
+        cfg = self.cfg
+
+        def body(carry, bp):
+            x, pk, pv, layer = carry
+            h = L.apply_norm(cfg, bp["norm1"], x)
+            a_out, pk, pv = attend(bp["attn"], h, pk, pv, layer)
+            x = self.constrain(x + a_out)
+            h = L.apply_norm(cfg, bp["norm2"], x)
+            y, _ = self._moe_or_mlp(bp, h)
+            return (self.constrain(x + y), pk, pv, layer + 1), None
+
+        with jax.named_scope("blocks"):
+            (x, pk, pv, _), _ = jax.lax.scan(
+                body, (x, pool["k"], pool["v"], jnp.zeros((), jnp.int32)),
+                params["blocks"])
+        return x, {"k": pk, "v": pv}
 
     def prefill_paged(self, params, pool, tokens, block_table, p0, last_idx):
         """One prompt chunk for ONE slot.  tokens: (1, C) at logical
@@ -300,24 +324,16 @@ class TransformerLM:
                     p0 + jnp.arange(c)][None]
             x = self.constrain(x)
 
-        def body(x, xs):
-            bp, (pk, pv) = xs
-            h = L.apply_norm(cfg, bp["norm1"], x)
-            a_out, pk, pv = A.attn_prefill_paged(cfg, bp["attn"], h, pk, pv,
-                                                 block_table, p0)
-            x = self.constrain(x + a_out)
-            h = L.apply_norm(cfg, bp["norm2"], x)
-            y, _ = self._moe_or_mlp(bp, h)
-            return self.constrain(x + y), (pk, pv)
+        def attend(p, h, pk, pv, layer):
+            return A.attn_prefill_paged(cfg, p, h, pk, pv, layer,
+                                        block_table, p0)
 
-        with jax.named_scope("blocks"):
-            x, kv = jax.lax.scan(body, x, (params["blocks"],
-                                           (pool["k"], pool["v"])))
+        x, pool = self._paged_blocks(params, pool, x, attend)
         with jax.named_scope("head"):
             x = L.apply_norm(cfg, params["final_norm"], x)
             xlast = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
             logits = self._lm_head(params, xlast)
-        return logits, {"k": kv[0], "v": kv[1]}
+        return logits, pool
 
     def decode_paged(self, params, pool, tokens, block_tables, positions):
         """One autoregressive step for ALL slots with PER-ROW positions.
@@ -332,20 +348,12 @@ class TransformerLM:
             if cfg.pos_embedding == "learned":
                 x = x + params["pos_embed"].astype(dt)[positions][:, None, :]
 
-        def body(x, xs):
-            bp, (pk, pv) = xs
-            h = L.apply_norm(cfg, bp["norm1"], x)
-            a_out, pk, pv = A.attn_decode_paged(cfg, bp["attn"], h, pk, pv,
-                                                block_tables, positions)
-            x = self.constrain(x + a_out)
-            h = L.apply_norm(cfg, bp["norm2"], x)
-            y, _ = self._moe_or_mlp(bp, h)
-            return self.constrain(x + y), (pk, pv)
+        def attend(p, h, pk, pv, layer):
+            return A.attn_decode_paged(cfg, p, h, pk, pv, layer,
+                                       block_tables, positions)
 
-        with jax.named_scope("blocks"):
-            x, kv = jax.lax.scan(body, x, (params["blocks"],
-                                           (pool["k"], pool["v"])))
+        x, pool = self._paged_blocks(params, pool, x, attend)
         with jax.named_scope("head"):
             x = L.apply_norm(cfg, params["final_norm"], x)
             logits = self._lm_head(params, x)
-        return logits, {"k": kv[0], "v": kv[1]}
+        return logits, pool

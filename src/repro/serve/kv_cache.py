@@ -1,13 +1,14 @@
 """Paged KV cache: fixed-size blocks, a free-list allocator, per-slot
 block tables.
 
-The device side is one POOL per layer — ``(L, num_blocks, block_size,
-KV, dh)``, built by ``model.init_paged_cache`` — shared by every serving
-slot.  A sequence owns an ordered list of block ids (its *block table*)
-and grows it as its position advances; on completion the blocks return
-to the free list and are reused by the next admitted request.  Long
-prompts therefore cost exactly ``ceil(len / block_size)`` blocks instead
-of the dense cache's ``cache_len`` worst-case reservation per slot.
+The device side is one POOL for all layers — ``(L, num_blocks,
+block_size, KV*dh)``, built by ``model.init_paged_cache`` — shared by
+every serving slot.  A sequence owns an ordered list of block ids (its
+*block table*) and grows it as its position advances; on completion the
+blocks return to the free list and are reused by the next admitted
+request.  Long prompts therefore cost exactly ``ceil(len / block_size)``
+blocks instead of the dense cache's ``cache_len`` worst-case reservation
+per slot.
 
 Block 0 is RESERVED as the null block and never handed out: engine-side
 block tables are padded (and idle decode rows parked) with 0, so padding
@@ -132,16 +133,18 @@ def pool_from_dense(model, dense_cache: dict, tables: list[SlotTable],
                     lengths: list[int], num_blocks: int,
                     block_size: int) -> dict:
     """Adopt a DENSE cache (``model.init_cache`` layout, (L, B, S, KV,
-    dh)) into a fresh block pool: slot b's first ``lengths[b]`` positions
-    are scattered into its table's blocks.  Used to migrate a wave
-    engine's in-flight state to the paged engine, and by the bitwise
-    parity tests to seed both representations identically."""
+    dh)) into a fresh block pool (L, NB, bs, KV*dh): slot b's first
+    ``lengths[b]`` positions are scattered into its table's blocks.
+    Used to migrate a wave engine's in-flight state to the paged engine,
+    and by the bitwise parity tests to seed both representations
+    identically."""
     import jax.numpy as jnp
 
     pool = model.init_paged_cache(num_blocks, block_size)
     out = {}
     for name in ("k", "v"):
         dense = np.asarray(dense_cache["kv"]._asdict()[name])
+        dense = dense.reshape(dense.shape[:3] + (-1,))  # (L, B, S, KV*dh)
         buf = np.asarray(pool[name]).copy()
         for b, (table, n) in enumerate(zip(tables, lengths)):
             for j in range(math.ceil(n / block_size)):

@@ -162,6 +162,46 @@ def test_paged_decode_bitwise_matches_dense(model_and_params):
         pos += 1
 
 
+def test_paged_calls_leave_other_blocks_untouched(model_and_params):
+    """The pool is updated in place, donated as the engine donates it: a
+    prefill chunk and decode steps write only blocks of the live tables
+    (and the null block, which parked rows write to); every other block
+    keeps its bytes."""
+    model, params = model_and_params
+    import jax.numpy as jnp
+    nb = 4 * NBT + 1
+    rng = np.random.default_rng(13)
+    shape = model.init_paged_cache(nb, BLOCK_SIZE)["k"].shape
+    before = {n: rng.standard_normal(shape).astype(jnp.bfloat16)
+              for n in ("k", "v")}
+    pool = {n: jnp.asarray(a) for n, a in before.items()}
+    live = [[5, 9, 2], [7, 11]]                   # slots 0, 1; 2, 3 idle
+    prefill = jax.jit(model.prefill_paged, donate_argnums=(1,))
+    decode = jax.jit(model.decode_paged, donate_argnums=(1,))
+    plen = 20                                     # padded to a 32 chunk
+    chunk = np.zeros((1, 32), np.int32)
+    chunk[0, :plen] = rng.integers(0, VOCAB, size=plen)
+    _, pool = prefill(params, pool, jnp.asarray(chunk),
+                      jnp.asarray(SlotTable(live[0]).padded(NBT)),
+                      jnp.int32(0), jnp.int32(plen - 1))
+    tabs = jnp.asarray(np.stack([SlotTable(t).padded(NBT)
+                                 for t in live + [[], []]]))
+    pos = np.array([plen, 10, 0, 0], np.int32)
+    toks = jnp.asarray(rng.integers(0, VOCAB, size=(4, 1)), jnp.int32)
+    for _ in range(5):
+        logits, pool = decode(params, pool, toks, tabs, jnp.asarray(pos))
+        toks = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)[:, None]
+        pos[:2] += 1
+    written = {NULL_BLOCK} | {b for t in live for b in t}
+    kept = [b for b in range(nb) if b not in written]
+    for n in ("k", "v"):
+        after = np.asarray(pool[n]).view(np.uint16)
+        was = before[n].view(np.uint16)
+        np.testing.assert_array_equal(after[:, kept], was[:, kept])
+        for b in (5, 9, 7):                       # what the calls wrote
+            assert (after[:, b] != was[:, b]).any()
+
+
 # ---------------------------------------------------------------------------
 # stream parity between schedulers
 # ---------------------------------------------------------------------------

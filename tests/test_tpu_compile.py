@@ -1,5 +1,6 @@
 """Compile every kernel ``kernels/ops.py`` can dispatch on a TPU for a
-described (not attached) v5e chip, at GPT-2 345M leaf shapes.
+described (not attached) v5e chip, at GPT-2 345M leaf shapes, and GPT-2
+117M's paged serving programs at the serving benchmark's shapes.
 
 Interpret mode cannot see what the TPU compiler refuses: scalar loads
 from un-placed memory, blocks not aligned to the (8, 128) tiling, or
@@ -14,6 +15,8 @@ file.  Keep all described-topology compiles in this one file.
 from __future__ import annotations
 
 import os
+import re
+from math import prod
 
 import jax
 import jax.numpy as jnp
@@ -47,17 +50,22 @@ def one_chip(topo):
 
 
 @pytest.fixture
-def compiled_pallas(monkeypatch):
-    """Kernel dispatch forced onto the compiled (non-interpret) Pallas
-    path, and the persistent cache off: what is compiled for a described
-    chip cannot be read back here."""
-    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
+def cache_off():
+    """The persistent cache off: what is compiled for a described chip
+    cannot be read back here."""
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         yield
     finally:
         jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+@pytest.fixture
+def compiled_pallas(monkeypatch, cache_off):
+    """Kernel dispatch forced onto the compiled (non-interpret) Pallas
+    path."""
+    monkeypatch.setattr(ops, "_use_pallas", lambda: (True, False))
 
 
 @pytest.fixture
@@ -189,3 +197,91 @@ def test_flash_attention_compiles(compiled_text, causal):
 
     text = compiled_text(fn, qkv, qkv, qkv)
     assert "tpu_custom_call" in text
+
+
+# -- paged serving: the KV pool is updated in place ---------------------------
+# GPT-2 117M as chipbench/configs/gpt2-117m.json serves it.
+SLOTS, CACHE_LEN, BLOCK, CHUNK = 128, 1024, 16, 512
+
+_INSTR = re.compile(
+    r"^\s*(ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(")
+_CALLS = re.compile(r"calls=%([\w.\-]+)")
+# opcodes that hand a buffer on (or write into it in place) without
+# making another array of its size
+_PASS_ON = {"parameter", "get-tuple-element", "bitcast", "scatter"}
+
+
+def _pool_sized_ops(text, nb, bs, width):
+    """Instructions of the compiled HLO ``text`` that make an array of
+    at least one layer of the pool (a dimension of ``nb`` blocks or ``nb
+    * bs`` rows), other than those in ``_PASS_ON`` and fusions whose root
+    is a scatter."""
+    roots, sized, comp = {}, [], None
+    for line in text.splitlines():
+        if line[:1] not in ("", " ") and line.rstrip().endswith("{"):
+            comp = line.removeprefix("ENTRY ").split()[0].lstrip("%")
+            continue
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        root, name, dims, op = m.groups()
+        if root:
+            roots[comp] = op
+        shape = [int(d) for d in dims.split(",") if d]
+        if ((nb in shape or nb * bs in shape)
+                and prod(shape) >= nb * bs * width):
+            sized.append((name, op, _CALLS.search(line)))
+    return [f"{name} ({op})" for name, op, calls in sized
+            if op not in _PASS_ON
+            and not (op == "fusion" and calls
+                     and roots.get(calls.group(1)) == "scatter")]
+
+
+@pytest.mark.parametrize("program,temp_gib", [("decode", 2.5),
+                                              ("prefill", 0.5)])
+def test_paged_serving_updates_pool_in_place(one_chip, cache_off, program,
+                                             temp_gib):
+    """The engine's decode step (all slots) and one prefill chunk, pool
+    donated as the engine donates it: the pool's output aliases the
+    donated argument, no instruction copies, slices or stacks the pool
+    (only scatters write it), and the temporaries stay small."""
+    from repro.configs import get_config
+    from repro.models import build_model
+
+    model = build_model(get_config("gpt2-117m"))
+    nbt = CACHE_LEN // BLOCK
+    nb = SLOTS * nbt + 1
+
+    def placed(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return placed(_s(shape, jnp.int32))
+
+    params = placed(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pool = placed(jax.eval_shape(lambda: model.init_paged_cache(nb, BLOCK)))
+
+    def decode(params, pool, tokens, tables, positions):
+        logits, pool = model.decode_paged(params, pool, tokens, tables,
+                                          positions)
+        return jnp.argmax(logits[:, -1, :], axis=-1), pool
+
+    def prefill(params, pool, tokens, table, p0, last_idx):
+        logits, pool = model.prefill_paged(params, pool, tokens, table, p0,
+                                           last_idx)
+        return jnp.argmax(logits[0, -1, :]), pool
+
+    if program == "decode":
+        fn, args = decode, (i32(SLOTS, 1), i32(SLOTS, nbt), i32(SLOTS))
+    else:
+        fn, args = prefill, (i32(1, CHUNK), i32(nbt), i32(), i32())
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, *args).compile()
+
+    layers, width = pool["k"].shape[0], prod(pool["k"].shape[3:])
+    assert _pool_sized_ops(compiled.as_text(), nb, BLOCK, width) == []
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * layers * nb * BLOCK * width * pool["k"].dtype.itemsize
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < temp_gib * 2 ** 30
