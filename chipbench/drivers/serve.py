@@ -16,10 +16,10 @@ that is rejected or never finishes counts as a miss (infinite latency).
 
 Correctness: after the run, with the engine freed, a sample drawn from the
 seed of the finished requests (the one with the most output tokens always
-among them) is run through the plain float32 reference, prompt and served
-tokens together; the widest gap by which a served token's reference logit
-lies below the reference's best logit at that position must stay within
-the limit.
+among them) is run through the configuration's plain float32 reference,
+prompt and served tokens together; the widest gap by which a served
+token's reference logit lies below the reference's best logit at that
+position must stay within the limit.
 """
 from __future__ import annotations
 
@@ -35,7 +35,6 @@ import numpy as np
 
 import faults
 import harness
-import reference
 import trace_reduce
 
 MISS = math.inf
@@ -117,7 +116,7 @@ class TraceWindow:
             self.closed = time.perf_counter()
 
 
-def build(config: dict, seed: int, sink, tracer=None):
+def build(config: dict, reference, seed: int, sink, tracer=None):
     from repro.config import ModelConfig
     from repro.models import build_model
     from repro.serve import ContinuousConfig, ContinuousEngine
@@ -191,7 +190,7 @@ def sample(reqs, seed: int, min_tokens: int, max_requests: int) -> list:
     return out
 
 
-def served_gaps(params, mdict, seqs: list, length: int,
+def served_gaps(reference, params, mdict, seqs: list, length: int,
                 precision: str = "f32") -> list:
     """Widest logit gap per request; ``seqs`` holds (prompt, served
     tokens).  At each position that produced a served token, the gap is
@@ -221,15 +220,16 @@ def served_gaps(params, mdict, seqs: list, length: int,
     return out
 
 
-def run(*, config, traffic, limits, seed, seconds, trace, t_process,
-        workdir, fault=None):
+def run(*, config, traffic, limits, reference, chips, seed, seconds, trace,
+        t_process, workdir, fault=None):
+    harness.one_chip(chips)
     from repro.telemetry.trace import Tracer
     mdict = config["model"]
     vocab = mdict["vocab"]
     sink = StampSink()
     spans = []
     tracer = Tracer(sink=harness.ListSink(spans)) if trace else None
-    engine = build(config, seed, sink, tracer)
+    engine = build(config, reference, seed, sink, tracer)
     warm_up(engine, vocab)
     faults.plant_serve(fault, engine, vocab)
     prompts, max_new, arrivals = make_requests(
@@ -263,7 +263,8 @@ def run(*, config, traffic, limits, seed, seconds, trace, t_process,
     # the reference makes its own weights from the seed
     params = jax.jit(functools.partial(reference.init_params, mdict))(
         harness.prng_key(jax, seed))
-    gaps = served_gaps(params, mdict, seqs, config["serve"]["cache_len"])
+    gaps = served_gaps(reference, params, mdict, seqs,
+                       config["serve"]["cache_len"])
     n_checked = sum(len(s) for _, s in seqs)
     checks = [harness.Check("served_logit_gap",
                             max(gaps) if gaps else math.nan,

@@ -11,10 +11,11 @@ boundary ``seconds`` later.  Nothing compiles in the window: the step
 program compiled at step 1 and both probe programs ran before it.
 
 After the window the peak memory is read, the program's state is freed,
-and the plain reference (``reference.py``, ``optim_ref.py``) runs the
-same first steps from the same seed; the run is correct when the step
-losses, the first gradient and the parameters' change agree leaf by leaf
-within the cell's limits (``limits/<workload>.json``).
+and the plain reference (the module the configuration names, with
+``optim_ref.py``) runs the same first steps from the same seed; the run is
+correct when the step losses, the first gradient and the parameters'
+change agree leaf by leaf within the cell's limits
+(``limits/<workload>.json``).
 
 The norms alone cannot see Adapprox's second moment: its first updates are
 RMS-clipped, so the norm of a factored leaf's change is set by the clip
@@ -39,7 +40,7 @@ import numpy as np
 import faults
 import harness
 import optim_ref
-import reference
+import stream
 import trace_reduce
 
 
@@ -168,8 +169,9 @@ class _Probe:
         pass
 
 
-def run(*, config, traffic, limits, seed, seconds, trace, t_process,
-        workdir, fault=None):
+def run(*, config, traffic, limits, reference, chips, seed, seconds, trace,
+        t_process, workdir, fault=None):
+    harness.one_chip(chips)
     from repro.config import ModelConfig
     from repro.core import build_optimizer
     from repro.data import DataConfig
@@ -236,7 +238,7 @@ def run(*, config, traffic, limits, seed, seconds, trace, t_process,
            "peak_hbm_gib": peak / harness.GIB,
            "setup_s": t_open - t_process}
 
-    checks = compare(mdict, ospec, seed, batch, seq, ref_steps,
+    checks = compare(reference, mdict, ospec, seed, batch, seq, ref_steps,
                      rows=traffic["ref_rows"], losses=losses,
                      first=probe.first, change=probe.change, limits=limits)
 
@@ -248,7 +250,7 @@ def run(*, config, traffic, limits, seed, seconds, trace, t_process,
                              e["t0_s"] + e["dur_s"] - probe.tracer_open)
                             for e in spans])
         context = {"kind": "train", "trace": reduced,
-                   "spans": spans, "model": mdict,
+                   "spans": spans, "model": mdict, "param_shapes": shapes,
                    "batch": batch, "seq": seq, "optimizer": ospec,
                    "steps_traced": last - warm, "window_s": window,
                    "traced_steps": list(range(warm + 1, last + 1))}
@@ -258,8 +260,8 @@ def run(*, config, traffic, limits, seed, seconds, trace, t_process,
                              context=context)
 
 
-def reference_readings(mdict, ospec, seed, batch, seq, ref_steps, rows,
-                       precision="f32") -> dict:
+def reference_readings(reference, mdict, ospec, seed, batch, seq, ref_steps,
+                       rows, precision="f32") -> dict:
     """The reference's step losses, first-gradient norms (every leaf),
     parameter change after ``ref_steps`` steps, which leaves keep a first
     moment of the gradient (the ones whose first gradient is compared),
@@ -273,8 +275,7 @@ def reference_readings(mdict, ospec, seed, batch, seq, ref_steps, rows,
     flat = flat0
     losses, first, v = [], None, None
     for t in range(ref_steps):
-        tokens = reference.synthetic_batch(mdict["vocab"], seq, batch,
-                                           seed, t)
+        tokens = stream.synthetic_batch(mdict["vocab"], seq, batch, seed, t)
         loss, grads = reference.loss_and_grad(
             jax.tree.unflatten(treedef, flat), tokens, mdict, rows,
             precision)
@@ -349,9 +350,10 @@ def readings(prog: dict, ref: dict) -> dict:
                 {i: r for i, r in ref["proj"].items() if keep[i]})}
 
 
-def compare(mdict, ospec, seed, batch, seq, ref_steps, rows, losses, first,
-            change, limits):
-    ref = reference_readings(mdict, ospec, seed, batch, seq, ref_steps, rows)
+def compare(reference, mdict, ospec, seed, batch, seq, ref_steps, rows,
+            losses, first, change, limits):
+    ref = reference_readings(reference, mdict, ospec, seed, batch, seq,
+                             ref_steps, rows)
     if first is None or change is None:
         nums = {}
     else:

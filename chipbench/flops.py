@@ -1,47 +1,29 @@
-"""Operation and byte counts from shapes, kept with the benchmark so that
-every PR computes them the same way.  ``model`` is a configuration's
-``model`` dict (GPT-2 layout: q, k, v, o and a two-matrix MLP per layer,
-output head tied to the token table)."""
+"""Operation and byte counts of the optimizer's kernels, from the shapes
+of the parameters (``jax.eval_shape`` of the configuration's reference's
+``init_params``) as ``optim_ref.route`` gives them to the optimizer's
+families, and the roofline share, kept with the benchmark so that every
+PR computes them the same way.  The model's own FLOP counts depend on its
+architecture and come from its reference (``reference.py``)."""
 from __future__ import annotations
+
+import math
+
+import optim_ref
 
 F32 = 4
 
 
-def matmul_params(model: dict) -> int:
-    """Weights that take part in a matrix product per token: the blocks'
-    projections and MLP, and the tied output head (the token and position
-    tables are read by lookup, not multiplied)."""
-    d, f = model["d_model"], model["d_ff"]
-    return model["n_layers"] * (4 * d * d + 2 * d * f) \
-        + model["vocab"] * d
+def _routed(shapes: list, opt: dict, family: str) -> list:
+    return [s for s, f in zip(shapes, optim_ref.route(shapes, opt))
+            if f == family]
 
 
-def train_flops_per_token(model: dict, seq: int) -> float:
-    """Forward plus backward, no recomputation counted: 6 per matmul
-    weight, plus causal attention.  A token at position i attends to i + 1
-    positions: QK^T and AV take 2 (i + 1) d each forward, so the mean over
-    a sequence of ``seq`` is 2 d (seq + 1) per layer forward, times 3."""
-    d, n = model["d_model"], model["n_layers"]
-    return 6.0 * matmul_params(model) + 6.0 * n * d * (seq + 1)
+def sketch_leaves(shapes: list, opt: dict) -> list:
+    """(rows, inner) of the tables the count-min sketch owns."""
+    return [(s[0], math.prod(s[1:])) for s in _routed(shapes, opt, "sketch")]
 
 
-def forward_flops(model: dict, context: float) -> float:
-    """One token forward through the model attending to ``context``
-    positions (itself included)."""
-    d, n = model["d_model"], model["n_layers"]
-    return 2.0 * matmul_params(model) + 4.0 * n * d * context
-
-
-def sketch_leaves(model: dict, opt: dict) -> list:
-    """(rows, inner) of the tables the count-min sketch owns: 2-D leaves
-    with at least ``embedding_min_rows`` rows."""
-    rows_min = opt["embedding_min_rows"]
-    leaves = [(model["vocab"], model["d_model"]),
-              (model["max_seq_len"], model["d_model"])]
-    return [s for s in leaves if s[0] >= rows_min]
-
-
-def sketch_update_cost(model: dict, opt: dict) -> tuple:
+def sketch_update_cost(shapes: list, opt: dict) -> tuple:
     """(flops, bytes) one step's sketch updates need: read the f32
     gradient and write the per-row estimate (rows x inner each), read and
     write the (depth, width, inner) table, read the bucket indices; per
@@ -49,26 +31,28 @@ def sketch_update_cost(model: dict, opt: dict) -> tuple:
     query."""
     depth, width = opt["sketch_depth"], opt["sketch_width"]
     flops = nbytes = 0
-    for rows, inner in sketch_leaves(model, opt):
+    for rows, inner in sketch_leaves(shapes, opt):
         nbytes += F32 * (2 * rows * inner + 2 * depth * width * inner
                          + depth * rows)
         flops += 3 * depth * rows * inner
     return float(flops), float(nbytes)
 
 
-def factored_matrices(model: dict) -> list:
-    """(count, m, n) of the stacked matrices Adapprox factors."""
-    d, f, n = model["d_model"], model["d_ff"], model["n_layers"]
-    return [(4 * n, d, d), (n, d, f), (n, f, d)]
+def factored_matrices(shapes: list, opt: dict) -> list:
+    """(count, m, n) of the stacked matrices Adapprox factors: a leaf's
+    leading axes count its matrices."""
+    return [(math.prod(s[:-2]), s[-2], s[-1])
+            for s in _routed(shapes, opt, "adapprox")]
 
 
-def fused_precond_cost(model: dict, rank: int, with_fold: bool) -> tuple:
+def fused_precond_cost(shapes: list, opt: dict, rank: int,
+                       with_fold: bool) -> tuple:
     """Pass 1 of the fused update: read G and both factors, write the raw
     update direction and (with the fold) the (n, r) projection; rebuild V
     tile-wise (2 m n r) and, with the fold, (G^2)^T Q (2 m n r), plus
     about 8 elementwise operations per entry."""
     flops = nbytes = 0
-    for count, m, n in factored_matrices(model):
+    for count, m, n in factored_matrices(shapes, opt):
         mm = 2 * m * n * rank * (2 if with_fold else 1)
         flops += count * (mm + 8 * m * n)
         nbytes += count * F32 * (2 * m * n + (m + n) * rank
@@ -76,12 +60,12 @@ def fused_precond_cost(model: dict, rank: int, with_fold: bool) -> tuple:
     return float(flops), float(nbytes)
 
 
-def fused_apply_cost(model: dict) -> tuple:
+def fused_apply_cost(shapes: list, opt: dict) -> tuple:
     """Pass 2 with the shared output: read the raw direction and the first
     moment, write the new first moment (= the step direction); clip scale
     and EMA, 3 operations per entry."""
     flops = nbytes = 0
-    for count, m, n in factored_matrices(model):
+    for count, m, n in factored_matrices(shapes, opt):
         flops += count * 3 * m * n
         nbytes += count * F32 * 3 * m * n
     return float(flops), float(nbytes)

@@ -57,6 +57,15 @@ def require_tpu(device: dict, chips: int) -> None:
                          f"sees {device['count']}")
 
 
+def one_chip(chips: int) -> None:
+    """A driver that does not shard refuses a cell that asks for more
+    than one chip: it would run on one device and report that chip's
+    numbers under the cell's name."""
+    if chips != 1:
+        raise SystemExit(f"chipbench: the cell asks for {chips} chips; this "
+                         f"driver runs on one and does not shard")
+
+
 def enable_cache(jax, root: Path) -> str:
     """The program's persistent compile cache (``$JAX_COMPILATION_CACHE_DIR``
     or a fixed directory in the checkout), keeping every program: the

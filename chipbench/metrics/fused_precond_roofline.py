@@ -14,7 +14,7 @@ def read(ctx):
     if count == 0 or seconds <= 0:
         return None
     knobs = ctx["optimizer"].get("knobs", {})
-    f, b = flops.fused_precond_cost(ctx["model"], RANK,
-                                    knobs.get("refresh_every", 1) > 1)
+    f, b = flops.fused_precond_cost(ctx["param_shapes"], ctx["optimizer"],
+                                    RANK, knobs.get("refresh_every", 1) > 1)
     n = ctx["steps_traced"]
     return flops.roofline_share(n * f, n * b, seconds, ctx["peaks"])[0]

@@ -11,6 +11,6 @@ def read(ctx):
     seconds, count = ctx["trace"].kernel_seconds(KERNEL)
     if count == 0 or seconds <= 0:
         return None
-    f, b = flops.sketch_update_cost(ctx["model"], ctx["optimizer"])
+    f, b = flops.sketch_update_cost(ctx["param_shapes"], ctx["optimizer"])
     n = ctx["steps_traced"]
     return flops.roofline_share(n * f, n * b, seconds, ctx["peaks"])[0]

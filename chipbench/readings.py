@@ -31,18 +31,18 @@ import harness  # noqa: E402
 import run as run_mod  # noqa: E402
 
 
-def control_train(config, traffic, seed):
+def control_train(config, traffic, reference, seed):
     drv = run_mod.load_module(HERE / "drivers" / "train.py")
     mdict, ospec = config["model"], traffic["optimizer"]
     batch, seq = config["train"]["batch"], config["train"]["seq"]
-    args = (mdict, ospec, seed, batch, seq, traffic["ref_steps"],
+    args = (reference, mdict, ospec, seed, batch, seq, traffic["ref_steps"],
             traffic["ref_rows"])
     ref = drv.reference_readings(*args, precision="f32")
     low = drv.reference_readings(*args, precision="fp8")
     return drv.readings(low, ref)
 
 
-def control_serve(config, traffic, seed, seconds):
+def control_serve(config, traffic, reference, seed, seconds):
     """The control's gap at each position of a run's own sampled prompts
     and served tokens: the sample comes from a sound run of the engine,
     then the engine is freed before the two reference forwards run."""
@@ -51,7 +51,7 @@ def control_serve(config, traffic, seed, seconds):
     drv = run_mod.load_module(HERE / "drivers" / "serve.py")
     mdict = config["model"]
     sink = drv.StampSink()
-    engine = drv.build(config, seed, sink)
+    engine = drv.build(config, reference, seed, sink)
     drv.warm_up(engine, mdict["vocab"])
     prompts, max_new, arrivals = drv.make_requests(
         traffic, traffic["rate"], seconds, seed, mdict["vocab"])
@@ -61,11 +61,12 @@ def control_serve(config, traffic, seed, seconds):
     seqs = [(r.prompt, list(r.out_tokens)) for r in picked]
     del engine
     gc.collect()
-    params = jax.jit(lambda k: drv.reference.init_params(mdict, k))(
+    params = jax.jit(lambda k: reference.init_params(mdict, k))(
         harness.prng_key(jax, seed))
     n = config["serve"]["cache_len"]
-    sound = drv.served_gaps(params, mdict, seqs, n)
-    low = drv.served_gaps(params, mdict, seqs, n, precision="fp8")
+    sound = drv.served_gaps(reference, params, mdict, seqs, n)
+    low = drv.served_gaps(reference, params, mdict, seqs, n,
+                          precision="fp8")
     del params
     jax.clear_caches()
     return {"served_logit_gap": max(low), "sound_gap": max(sound),
@@ -83,15 +84,17 @@ def main(argv=None) -> int:
     cell = harness.find(bench["workloads"], args.workload, "workload")
     config = harness.load_json(HERE / "configs" / f"{cell['config']}.json")
     traffic = harness.load_json(HERE / "traffic" / f"{cell['traffic']}.json")
+    reference = run_mod.load_reference(HERE, config)
     import jax
     harness.require_tpu(harness.device_info(jax), cell["chips"])
     harness.enable_cache(jax, run_mod.ROOT)
     for seed in (int(s) for s in args.seeds.split(",")):
         if args.mode == "control":
             if traffic["driver"] == "train":
-                out = control_train(config, traffic, seed)
+                out = control_train(config, traffic, reference, seed)
             else:
-                out = control_serve(config, traffic, seed, args.seconds)
+                out = control_serve(config, traffic, reference, seed,
+                                    args.seconds)
         elif args.mode == "sound" or args.mode.startswith("fault:"):
             fault = (args.mode.split(":", 1)[1]
                      if args.mode.startswith("fault:") else None)

@@ -1,43 +1,40 @@
-"""The plain reference the benchmark holds the program to: GPT-2 as this
+"""The plain reference the benchmark holds GPT-2 to: GPT-2 as this
 repository's model configuration defines it (pre-norm LayerNorm blocks,
 learned positions, tanh-GELU MLP with biases, no q/k/v biases, output head
-tied to the token embedding), the synthetic token stream the train loop
-feeds, and the weights every run starts from.
+tied to the token embedding), the weights every run starts from, and its
+FLOP counts.
 
-It imports nothing of the program.  Matrix products run in float32 at
-``Precision.HIGHEST``; ``precision="fp8"`` rounds both operands of every
-product to float8 e4m3 with a per-tensor scale first, which is the
-lower-precision control that a sound comparison must reject.  Long
-batches go through in blocks of rows, each layer rematerialised, so the
-reference fits beside nothing else on one chip.
+A configuration names its reference by its ``reference`` key, a path
+relative to the benchmark directory; every part of a run that depends on
+the architecture comes from that module.  A reference provides:
+
+- ``init_params(model, key)``: the weights, in the program's parameter
+  layout, made on the device from ``key`` (called under ``jax.jit``);
+- ``loss_and_grad(params, tokens, model, rows, precision)``: the mean
+  next-token loss over the batch and its gradient, ``rows`` rows at a
+  time;
+- ``logits(params, tokens, model, precision)``: (B, T, vocab) logits of a
+  causal forward;
+- ``train_flops_per_token(model, seq)``: the model FLOPs of one trained
+  token, forward and backward, no recomputation counted;
+- ``forward_flops(model, context)``: one token forward, attending to
+  ``context`` positions.
+
+``model`` is the configuration's ``model`` dict; ``precision`` is
+``"f32"``, or ``"fp8"`` for the lower-precision control (``refops.py``).
+The reference imports nothing of the program.  Long batches go through in
+blocks of rows, each layer rematerialised, so the reference fits beside
+nothing else on one chip.
 """
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-HIGHEST = jax.lax.Precision.HIGHEST
-F8_MAX = 448.0          # largest finite float8 e4m3fn
-
-
-def _round(x, precision: str):
-    if precision == "f32":
-        return x.astype(jnp.float32)
-    if precision == "fp8":
-        x = x.astype(jnp.float32)
-        s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / F8_MAX
-        return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
-    raise ValueError(precision)
-
-
-def dot(spec: str, a, b, precision: str):
-    return jnp.einsum(spec, _round(a, precision), _round(b, precision),
-                      precision=HIGHEST)
-
+import refops
+from refops import dot
 
 # ---------------------------------------------------------------------------
 # Weights: made on the device from the seed, in one jitted call
@@ -127,58 +124,38 @@ def logits(params, tokens, model: dict, precision: str = "f32"):
                params["embed"], precision)
 
 
-def mean_nll(params, tokens, model: dict, precision: str = "f32"):
-    """Mean next-token cross entropy over every position of the rows."""
-    z = logits(params, tokens, model, precision)[:, :-1]
-    gold = jnp.take_along_axis(z, tokens[:, 1:, None], axis=-1)[..., 0]
-    return jnp.mean(jax.scipy.special.logsumexp(z, axis=-1) - gold)
-
-
-@functools.partial(jax.jit, static_argnames=("model_items", "rows",
-                                             "precision"))
-def _loss_and_grad(params, tokens, model_items, rows, precision):
-    model = dict(model_items)
-    blocks = tokens.reshape(-1, rows, tokens.shape[1])
-    grad_fn = jax.value_and_grad(mean_nll)
-
-    def body(acc, blk):
-        loss, g = grad_fn(params, blk, model, precision)
-        return jax.tree.map(jnp.add, acc, (loss, g)), None
-
-    zero = (jnp.zeros((), jnp.float32), jax.tree.map(jnp.zeros_like, params))
-    (loss, g), _ = jax.lax.scan(body, zero, blocks)
-    n = blocks.shape[0]
-    return loss / n, jax.tree.map(lambda x: x / n, g)
 
 
 def loss_and_grad(params, tokens, model: dict, rows: int,
                   precision: str = "f32"):
-    """Mean loss over the batch and its gradient, ``rows`` rows at a time
-    (every row has the same number of positions, so the batch mean is
-    the mean of the block means)."""
-    items = tuple(sorted((k, v) for k, v in model.items()
-                         if isinstance(v, (int, float, str))))
-    return _loss_and_grad(params, jnp.asarray(tokens), items, rows,
-                          precision)
+    return refops.loss_and_grad(logits, params, tokens, model, rows,
+                                precision)
 
 
 # ---------------------------------------------------------------------------
-# The train loop's synthetic token stream, written out from its definition
+# FLOP counts (mfu.train, mfu.serve)
 # ---------------------------------------------------------------------------
 
-def synthetic_batch(vocab: int, seq: int, rows: int, seed: int,
-                    step: int) -> np.ndarray:
-    """Row i of step s: Zipf(1.1) unigrams from
-    ``default_rng(SeedSequence([seed, s, i]))`` with the second half of
-    the row a copy of the first (an induction pattern)."""
-    ranks = np.arange(1, vocab + 1, dtype=np.float64)
-    probs = 1.0 / ranks ** 1.1
-    probs = probs / probs.sum()
-    out = np.empty((rows, seq), np.int32)
-    half = seq // 2
-    for i in range(rows):
-        rng = np.random.default_rng(np.random.SeedSequence([seed, step, i]))
-        row = rng.choice(vocab, size=seq, p=probs)
-        row[half:2 * half] = row[:half]
-        out[i] = row
-    return out
+def matmul_params(model: dict) -> int:
+    """Weights that take part in a matrix product per token: the blocks'
+    projections and MLP, and the tied output head (the token and position
+    tables are read by lookup, not multiplied)."""
+    d, f = model["d_model"], model["d_ff"]
+    return model["n_layers"] * (4 * d * d + 2 * d * f) \
+        + model["vocab"] * d
+
+
+def train_flops_per_token(model: dict, seq: int) -> float:
+    """Forward plus backward, no recomputation counted: 6 per matmul
+    weight, plus causal attention.  A token at position i attends to i + 1
+    positions: QK^T and AV take 2 (i + 1) d each forward, so the mean over
+    a sequence of ``seq`` is 2 d (seq + 1) per layer forward, times 3."""
+    d, n = model["d_model"], model["n_layers"]
+    return 6.0 * matmul_params(model) + 6.0 * n * d * (seq + 1)
+
+
+def forward_flops(model: dict, context: float) -> float:
+    """One token forward through the model attending to ``context``
+    positions (itself included)."""
+    d, n = model["d_model"], model["n_layers"]
+    return 2.0 * matmul_params(model) + 4.0 * n * d * context

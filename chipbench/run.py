@@ -7,18 +7,23 @@
 The cell is looked up by name in ``BENCHMARK.json`` at the checkout root;
 its configuration, traffic mix and correctness limits are data files found
 by name (``configs/<config>.json``, ``traffic/<traffic>.json``,
-``limits/<workload>.json``), the traffic file names the driver that runs it
-(``drivers/<driver>.py``), and every per-layer metric is a reader of its
-own (``metrics/<metric>.py``).  Adding a cell, a configuration, a mix or a
-metric is adding files and entries; nothing here changes.
+``limits/<workload>.json``), the configuration names its plain reference
+(``reference``: the module that makes the weights and gives the loss,
+the logits and the FLOP counts; see ``reference.py``), the traffic file
+names the driver that runs it (``drivers/<driver>.py``), and every
+per-layer metric is a reader of its own (``metrics/<metric>.py``).  Adding
+a cell, a configuration, an architecture, a mix or a metric is adding
+files and entries; nothing here changes.
 
 The run fails, printing no result, when JAX finds no TPU or fewer chips
-than the cell asks for.  With ``--trace 0`` the result carries the cell's
-end-to-end metrics, with ``--trace 1`` its per-layer metrics read from a
-profiler trace of part of the window.  Every run checks what the timed
-path produced against a plain float32 reference and prints each number
-compared beside its limit, last on stderr and under ``checks`` (the last
-key) of the result line, which is the last line of stdout.
+than the cell asks for, or when the cell asks for more chips than its
+driver shards over (no driver shards yet).  With ``--trace 0`` the result
+carries the cell's end-to-end metrics, with ``--trace 1`` its per-layer
+metrics read from a profiler trace of part of the window.  Every run
+checks what the timed path produced against the plain float32 reference
+and prints each number compared beside its limit, last on stderr and
+under ``checks`` (the last key) of the result line, which is the last
+line of stdout.
 """
 from __future__ import annotations
 
@@ -49,14 +54,27 @@ def parse(argv=None):
 
 
 def load_module(path: Path):
-    """Import a harness plug-in (driver or metric reader) by file path;
-    names may hold dots, so they are not importable as packages."""
+    """Import a harness plug-in (driver, metric reader or reference) by
+    file path; names may hold dots, so they are not importable as
+    packages."""
     name = "chipbench_" + path.stem.replace(".", "_").replace("-", "_")
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reference(base: Path, config: dict):
+    """The module a configuration names as its plain reference, a path
+    relative to the benchmark directory ``base``."""
+    name = config.get("reference")
+    if not name:
+        raise SystemExit("chipbench: the configuration names no reference")
+    path = (base / name).resolve()
+    if not path.is_relative_to(base.resolve()) or not path.is_file():
+        raise SystemExit(f"chipbench: no reference {name!r} under {base}")
+    return load_module(path)
 
 
 def main(argv=None, *, require_chip: bool = True, fault: str | None = None,
@@ -79,8 +97,10 @@ def main(argv=None, *, require_chip: bool = True, fault: str | None = None,
         harness.require_tpu(device, cell["chips"])
         harness.enable_cache(jax, ROOT)
 
+    reference = load_reference(base, config)
     driver = load_module(base / "drivers" / f"{traffic['driver']}.py")
     run = driver.run(config=config, traffic=traffic, limits=limits,
+                     reference=reference, chips=cell["chips"],
                      seed=args.seed, seconds=args.seconds,
                      trace=bool(args.trace), t_process=T_PROCESS,
                      workdir=harness.workdir(base, args.workload),
@@ -91,6 +111,7 @@ def main(argv=None, *, require_chip: bool = True, fault: str | None = None,
         device["busy_s"] = run.trace.busy_s
         device["window_s"] = run.trace.window_s
         wanted = harness.per_layer_for(bench, args.workload)
+        run.context["reference"] = reference
         run.context["peaks"] = harness.peaks_for(
             harness.load_json(base / "peaks.json"), device["kind"])
         metrics = {}

@@ -46,7 +46,8 @@ def main(argv=None) -> int:
     drv = run_mod.load_module(HERE / "drivers" / "serve.py")
     vocab = config["model"]["vocab"]
     sink = drv.StampSink()
-    engine = drv.build(config, args.seed, sink)
+    engine = drv.build(config, run_mod.load_reference(HERE, config),
+                       args.seed, sink)
     drv.warm_up(engine, vocab)
     for rate in (float(r) for r in args.rates.split(",")):
         prompts, max_new, arrivals = drv.make_requests(

@@ -1,6 +1,7 @@
 """A benchmark directory at a size a CPU test can hold: the real drivers,
-metric readers and peaks, with tiny configurations, mixes and limits of
-its own, laid out the way the harness finds the real ones by name."""
+metric readers, GPT-2 reference and peaks, with tiny configurations, mixes
+and limits of its own, laid out the way the harness finds the real ones
+by name."""
 from __future__ import annotations
 
 import io
@@ -47,17 +48,20 @@ def _dump(path: Path, obj) -> None:
 
 
 def make_base(tmp: Path) -> Path:
-    """Copy the drivers, metric readers and peaks; write tiny data files
-    and a BENCHMARK.json naming the tiny cells."""
+    """Copy the drivers, metric readers, GPT-2 reference and peaks; write
+    tiny data files and a BENCHMARK.json naming the tiny cells."""
     base = tmp / "chipbench"
     for d in ("drivers", "metrics"):
         shutil.copytree(HERE / d, base / d)
-    shutil.copy(HERE / "peaks.json", base / "peaks.json")
+    for f in ("peaks.json", "reference.py"):
+        shutil.copy(HERE / f, base / f)
     _dump(base / "configs" / "tiny-train.json",
-          {"model": dict(_MODEL, arch="tiny-train", vocab=1024,
+          {"reference": "reference.py",
+           "model": dict(_MODEL, arch="tiny-train", vocab=1024,
                          max_seq_len=64), "train": {"batch": 4, "seq": 32}})
     _dump(base / "configs" / "tiny-serve.json",
-          {"model": dict(_MODEL, arch="tiny-serve", vocab=512,
+          {"reference": "reference.py",
+           "model": dict(_MODEL, arch="tiny-serve", vocab=512,
                          max_seq_len=128),
            "serve": {"slots": 4, "cache_len": 128, "block_size": 16,
                      "prefill_chunk": 32}})

@@ -61,11 +61,14 @@ def test_committed_benchmark_names_existing_files():
 
 def test_dropped_in_files_are_found_by_name(tmp_path):
     base = T.make_base(tmp_path)
-    # a new configuration, mix, limits file and metric, and the entries
-    # that name them: no file of the harness changes
+    # a new configuration with a reference of its own, mix, limits file
+    # and metric, and the entries that name them: no file of the harness
+    # changes
     cfg = json.loads((base / "configs" / "tiny-train.json").read_text())
     cfg["model"]["arch"] = "tiny-wide"
     cfg["model"]["d_ff"] = 512
+    cfg["reference"] = "reference_wide.py"
+    shutil.copy(base / "reference.py", base / "reference_wide.py")
     (base / "configs" / "tiny-wide.json").write_text(json.dumps(cfg))
     mix = json.loads((base / "traffic" / "train.adamw.json").read_text())
     mix["warm_steps"] = 5
@@ -95,6 +98,7 @@ def test_dropped_in_files_are_found_by_name(tmp_path):
     assert reader.read({"steps_traced": 3}) == 3.0
     res = T.run_cell(base, "tiny-wide.train.long", seed=11)
     assert res["correct"], res["checks"]
+    assert "chipbench_reference_wide" in sys.modules
     assert set(res["metrics"]) == {"train_tokens_per_s", "peak_hbm_gib",
                                    "setup_s"}
 

@@ -1,5 +1,6 @@
-"""The per-layer readers of the optimizer's device time and the serving
-scheduler's host time, on hand-made traces and span lists."""
+"""The per-layer readers of the optimizer's device time, the serving
+scheduler's host time and the model FLOP/s utilisation, on hand-made
+traces and span lists."""
 from types import SimpleNamespace as NS
 
 import pytest
@@ -10,6 +11,8 @@ import trace_reduce as R
 
 OPT = run.load_module(T.HERE / "metrics" / "optimizer_ms_per_step.train.py")
 HOST = run.load_module(T.HERE / "metrics" / "host_ms_per_step.serve.py")
+MFU_TRAIN = run.load_module(T.HERE / "metrics" / "mfu.train.py")
+MFU_SERVE = run.load_module(T.HERE / "metrics" / "mfu.serve.py")
 
 
 def _reduced(ops):
@@ -127,3 +130,38 @@ def test_host_time_is_none_without_the_split():
     ctx = {"spans": spans, "window": (30.0, 32.0), "tracer_open": 100.0}
     assert HOST.read(ctx) is None
     assert HOST.read(dict(ctx, spans=[])) is None
+
+
+def _stub_reference():
+    """FLOP counts of no real model; the model dict the readers pass is
+    empty, so GPT-2's formulas could not read it."""
+    return NS(train_flops_per_token=lambda model, seq: 1e9 + seq,
+              forward_flops=lambda model, context: 1e6 * context)
+
+
+def test_mfu_train_reads_the_count_of_the_runs_reference():
+    ctx = {"trace": NS(window_s=2.0, n_devices=1), "steps_traced": 3,
+           "batch": 2, "seq": 8, "model": {},
+           "reference": _stub_reference(),
+           "peaks": {"bf16_flops_per_s": 1e12}}
+    assert MFU_TRAIN.read(ctx) == pytest.approx(
+        100.0 * 3 * 2 * 8 * (1e9 + 8) / (2.0 * 1e12))
+
+
+def test_mfu_serve_reads_the_count_of_the_runs_reference():
+    """The window is 10 to 12 s into the run (100 s on the tracer's
+    clock at its opening): a 4-token prefill chunk from position 2 inside
+    it, and one request whose two decode tokens fall inside it, at
+    contexts 6 and 7; a chunk after the window does not count."""
+    spans = [{"name": "prefill_chunk", "t0_s": 100.5,
+              "attrs": {"p0": 2, "tokens": 4}},
+             {"name": "prefill_chunk", "t0_s": 103.0,
+              "attrs": {"p0": 0, "tokens": 8}}]
+    req = NS(uid=0, prompt=[0] * 5, out_tokens=[1, 2, 3])
+    ctx = {"trace": NS(window_s=2.0, n_devices=1), "model": {},
+           "reference": _stub_reference(), "window": (10.0, 12.0),
+           "tracer_open": 100.0, "spans": spans, "t0": 50.0,
+           "stamps": NS(first={0: 60.5}, done={0: 61.5}),
+           "requests": [req], "peaks": {"bf16_flops_per_s": 1e12}}
+    work = 1e6 * (3 + 4 + 5 + 6 + 6 + 7)
+    assert MFU_SERVE.read(ctx) == pytest.approx(100.0 * work / 2e12)

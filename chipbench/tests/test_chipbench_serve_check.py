@@ -32,15 +32,16 @@ def test_lower_precision_control_fails(base):
     drv = run.load_module(T.HERE / "drivers" / "serve.py")
     cfg = json.loads((base / "configs" / "tiny-serve.json").read_text())
     traffic = json.loads((base / "traffic" / "serve.chat.json").read_text())
-    engine = drv.build(cfg, 4, drv.StampSink())
+    reference = run.load_reference(base, cfg)
+    engine = drv.build(cfg, reference, 4, drv.StampSink())
     drv.warm_up(engine, cfg["model"]["vocab"])
     prompts, max_new, arrivals = drv.make_requests(
         traffic, traffic["rate"], 1.0, 4, cfg["model"]["vocab"])
     reqs, _ = drv.serve(engine, prompts, max_new, arrivals)
     seqs = [(r.prompt, list(r.out_tokens))
             for r in drv.sample(reqs, 4, 30, 8)]
-    gaps = drv.served_gaps(engine.params, cfg["model"], seqs, 128,
-                           precision="fp8")
+    gaps = drv.served_gaps(reference, engine.params, cfg["model"], seqs,
+                           128, precision="fp8")
     assert max(gaps) > T.SERVE_LIMITS["served_logit_gap"], gaps
 
 

@@ -45,11 +45,12 @@ def test_lower_precision_control_fails():
     import json
     import run
     drv = run.load_module(T.HERE / "drivers" / "train.py")
+    import reference
     cfg = {"model": dict(T._MODEL, arch="tiny-train", vocab=1024,
                          max_seq_len=64)}
     traffic = json.loads((T.HERE / "traffic" / "train.adapprox.json")
                          .read_text())
-    args = (cfg["model"], traffic["optimizer"], 3, 4, 32,
+    args = (reference, cfg["model"], traffic["optimizer"], 3, 4, 32,
             traffic["ref_steps"], traffic["ref_rows"])
     ref = drv.reference_readings(*args, precision="f32")
     low = drv.reference_readings(*args, precision="fp8")
